@@ -29,9 +29,11 @@ import argparse
 import time
 
 from benchmarks.emit import emit
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI subset: one tuning benchmark end-to-end")

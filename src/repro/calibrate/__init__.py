@@ -16,17 +16,18 @@ from .probes import (collective_bw_sweep, dispatch_latency_sweep,
                      ensure_calibrated, fit_bandwidth, fit_dispatch_us,
                      fit_link_bw, fit_peak_flops, matmul_flops_sweep,
                      memory_bw_sweep, run_calibration)
-from .spec import (DEFAULT_SPEC, SPEC_KIND, SPEC_SCHEMA, CalibrationError,
-                   PlatformSpec, calibration_hash, device_fingerprint,
-                   get_platform_spec, load_spec, set_platform_spec,
-                   spec_path)
+from .spec import (DEFAULT_SPEC, DEVICE_SPECS, SPEC_KIND, SPEC_SCHEMA,
+                   CalibrationError, PlatformSpec, UnknownDeviceError,
+                   calibration_hash, device_fingerprint, get_platform_spec,
+                   load_spec, set_platform_spec, spec_path)
 from .trajectory import (TRAJECTORY_PATH, append_run, gap_from_stats,
                          load_trajectory, measure_gap, run_trajectory)
 
 __all__ = [
     # spec + resolver
-    "PlatformSpec", "CalibrationError", "DEFAULT_SPEC", "SPEC_SCHEMA",
-    "SPEC_KIND", "load_spec", "spec_path", "get_platform_spec",
+    "PlatformSpec", "CalibrationError", "UnknownDeviceError",
+    "DEFAULT_SPEC", "DEVICE_SPECS", "SPEC_SCHEMA", "SPEC_KIND",
+    "load_spec", "spec_path", "get_platform_spec",
     "set_platform_spec", "calibration_hash", "device_fingerprint",
     # probes
     "matmul_flops_sweep", "memory_bw_sweep", "dispatch_latency_sweep",

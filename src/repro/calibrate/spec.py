@@ -24,7 +24,12 @@ Resolution order (:func:`get_platform_spec`):
 2. a calibration artifact on disk (``$REPRO_PLATFORM_SPEC`` or
    ``~/.cache/repro/platform_spec.json``) whose schema is current and
    whose backend/device match the running process,
-3. :data:`DEFAULT_SPEC` (the TPU v5e constants).
+3. the datasheet peaks of the running device, looked up in
+   :data:`DEVICE_SPECS` by the kind JAX reports — a TPU whose kind is
+   not in the table is an :class:`UnknownDeviceError`, never another
+   chip's numbers.  On the CPU, where nothing runs at the speed of a
+   chip, the cost models keep modelling the target chip:
+   :data:`DEFAULT_SPEC` (the TPU v5e constants).
 
 :meth:`PlatformSpec.calibration_hash` is mixed into the tuning-cache
 platform fingerprint (:func:`repro.tune.cache.platform_fingerprint`) so
@@ -59,18 +64,18 @@ class CalibrationError(ValueError):
     stale schema version)."""
 
 
-def device_fingerprint() -> dict[str, str]:
-    """Backend + chip generation of the running process (same shape as
-    :func:`repro.tune.cache.platform_fingerprint`'s device part, local
-    so the import graph stays calibrate -> nothing)."""
+class UnknownDeviceError(RuntimeError):
+    """The running accelerator has no entry in :data:`DEVICE_SPECS`."""
 
-    try:
-        import jax
-        dev = jax.devices()[0]
-        return {"backend": jax.default_backend(),
-                "device_kind": str(getattr(dev, "device_kind", "unknown"))}
-    except Exception:                                  # pragma: no cover
-        return {"backend": "unknown", "device_kind": "unknown"}
+
+def device_fingerprint() -> dict[str, str]:
+    """Backend + chip generation of the running process (the device part
+    of :func:`repro.tune.cache.platform_fingerprint`).  A backend that
+    fails to start raises: no device is ever reported as unknown."""
+
+    import jax
+    return {"backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind}
 
 
 @dataclass(frozen=True)
@@ -189,11 +194,34 @@ def load_spec(path: str | os.PathLike) -> PlatformSpec:
 
 # the TPU v5e constants every cost model used before calibration existed
 # (197 TFLOP/s bf16 MXU, 819 GB/s HBM, 4 x 50 GB/s ICI, 25 GB/s DCI,
-# 50 us dispatch) — now stated exactly once, here
+# 50 us dispatch; Google Cloud documentation, "TPU v5e") — now stated
+# exactly once, here.  JAX reports the v5e as "TPU v5 lite".
 DEFAULT_SPEC = PlatformSpec(peak_flops=197e12, hbm_bw=819e9,
                             link_bw=50e9, links=4, dci_bw=25e9,
                             dispatch_us=50.0, source="default",
-                            backend="tpu", device_kind="TPU v5e")
+                            backend="tpu", device_kind="TPU v5 lite")
+
+# datasheet peaks keyed by the device kind JAX reports
+DEVICE_SPECS: Mapping[str, PlatformSpec] = {
+    DEFAULT_SPEC.device_kind: DEFAULT_SPEC,
+}
+
+
+def datasheet_spec(dev: Mapping[str, str]) -> PlatformSpec:
+    """The uncalibrated spec for ``dev``: its :data:`DEVICE_SPECS` entry
+    on an accelerator, :data:`DEFAULT_SPEC` (the modelled target chip)
+    on the CPU.  An accelerator not in the table raises."""
+
+    if dev["backend"] == "cpu":
+        return DEFAULT_SPEC
+    spec = DEVICE_SPECS.get(dev["device_kind"])
+    if spec is None:
+        raise UnknownDeviceError(
+            f"no datasheet spec for {dev['backend']} device "
+            f"{dev['device_kind']!r} (known: {sorted(DEVICE_SPECS)}); add "
+            f"it to repro.calibrate.spec.DEVICE_SPECS or install a "
+            f"calibration with `python -m repro.calibrate run`")
+    return spec
 
 
 def spec_path(path: str | os.PathLike | None = None) -> Path:
@@ -206,7 +234,7 @@ def spec_path(path: str | os.PathLike | None = None) -> Path:
 
 
 _active_spec: PlatformSpec | None = None
-_loaded: tuple[Path, PlatformSpec | None] | None = None
+_loaded: tuple[Path, PlatformSpec] | None = None
 
 
 def set_platform_spec(spec: PlatformSpec | None) -> PlatformSpec | None:
@@ -225,14 +253,16 @@ def get_platform_spec() -> PlatformSpec:
     """Resolve the active platform spec (see module docstring for the
     order).  A disk artifact is only honored when its schema is current
     AND it was calibrated on this process's backend/device — a spec
-    fitted on a TPU must not price CPU runs."""
+    fitted on a TPU must not price CPU runs.  Raises
+    :class:`UnknownDeviceError` on an accelerator with neither."""
 
     global _loaded
     if _active_spec is not None:
         return _active_spec
     path = spec_path()
     if _loaded is not None and _loaded[0] == path:
-        return _loaded[1] or DEFAULT_SPEC
+        return _loaded[1]
+    dev = device_fingerprint()
     resolved: PlatformSpec | None = None
     try:
         spec = load_spec(path)
@@ -240,8 +270,10 @@ def get_platform_spec() -> PlatformSpec:
             resolved = spec
     except (OSError, CalibrationError):
         resolved = None
+    if resolved is None:
+        resolved = datasheet_spec(dev)
     _loaded = (path, resolved)
-    return resolved or DEFAULT_SPEC
+    return resolved
 
 
 def calibration_hash() -> str:
@@ -259,7 +291,8 @@ def calibrated_replace(spec: PlatformSpec, **fitted: Any) -> PlatformSpec:
     return replace(spec, source="calibrated", created=time.time(), **fitted)
 
 
-__all__ = ["PlatformSpec", "CalibrationError", "DEFAULT_SPEC", "SPEC_SCHEMA",
-           "SPEC_KIND", "load_spec", "spec_path", "get_platform_spec",
-           "set_platform_spec", "calibration_hash", "calibrated_replace",
+__all__ = ["PlatformSpec", "CalibrationError", "UnknownDeviceError",
+           "DEFAULT_SPEC", "DEVICE_SPECS", "SPEC_SCHEMA", "SPEC_KIND",
+           "load_spec", "spec_path", "get_platform_spec", "set_platform_spec",
+           "calibration_hash", "calibrated_replace",
            "device_fingerprint"]

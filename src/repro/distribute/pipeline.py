@@ -22,7 +22,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe(stage_fn: Callable, stage_params, x: jax.Array, *, mesh: Mesh,
@@ -67,8 +66,8 @@ def gpipe(stage_fn: Callable, stage_params, x: jax.Array, *, mesh: Mesh,
         return jax.lax.psum(outs, axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh, in_specs=(spec_params, P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=(spec_params, P()),
+                       out_specs=P(), check_vma=False)
     outs = fn(stage_params, xm)
     return outs.reshape(B, *x.shape[1:])
 

@@ -104,8 +104,7 @@ _CTX: contextvars.ContextVar[MeshCtx | None] = contextvars.ContextVar(
 def use_mesh(mesh: Mesh, rules: Rules):
     token = _CTX.set(MeshCtx(mesh, rules))
     try:
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else contextlib.nullcontext():
+        with jax.set_mesh(mesh):
             yield
     finally:
         _CTX.reset(token)

@@ -15,6 +15,30 @@ from __future__ import annotations
 import time
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
+
+# Scoped VMEM every Pallas kernel here asks the TPU compiler for, and the
+# budget each ``tuning_space`` counts its blocks against.  Stating it
+# explicitly keeps the lattice independent of the compiler's default scope
+# (which refused the larger blocks); a v5e TensorCore has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 32 * 2**20
+
+
+def tpu_compiler_params() -> pltpu.CompilerParams:
+    """Mosaic parameters shared by the kernels: the VMEM scope above."""
+
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def fits_vmem(*, blocks: int, scratch: int = 0) -> bool:
+    """Whether a kernel's VMEM footprint fits :data:`VMEM_LIMIT_BYTES`.
+
+    ``blocks`` is the bytes of one copy of every pipelined input and
+    output block: Pallas double-buffers each, so they count twice.
+    ``scratch`` is what is allocated once (scratch shapes and the
+    kernel body's large intermediates)."""
+
+    return 2 * blocks + scratch <= VMEM_LIMIT_BYTES
 
 
 def is_cpu() -> bool:
@@ -57,4 +81,5 @@ def time_fn(fn, *, warmup: int = 1, iters: int = 3) -> float:
     return median(samples)
 
 
-__all__ = ["is_cpu", "median", "resolve_interpret", "time_fn"]
+__all__ = ["VMEM_LIMIT_BYTES", "fits_vmem", "is_cpu", "median",
+           "resolve_interpret", "time_fn", "tpu_compiler_params"]

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import tpu_compiler_params
+
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -119,6 +121,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, D), jnp.float32),     # output accumulator
         ],
+        compiler_params=tpu_compiler_params(),
         interpret=interpret,
     )(q, k, v)
 

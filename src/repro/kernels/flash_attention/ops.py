@@ -17,13 +17,12 @@ import jax.numpy as jnp
 
 from ...core.search_space import Param, SearchSpace
 from ...tune import autotune
-from ..common import resolve_interpret, time_fn
+from ..common import fits_vmem, resolve_interpret, time_fn
 from .kernel import flash_attention_bhsd
 from .ref import attention_ref
 
 
-def tuning_space(S: int, D: int, dtype_bytes: int = 2,
-                 vmem_bytes: int = 64 * 2**20) -> SearchSpace:
+def tuning_space(S: int, D: int, dtype_bytes: int = 2) -> SearchSpace:
     def blocks(dim: int) -> tuple[int, ...]:
         vals = []
         v = 128
@@ -35,10 +34,14 @@ def tuning_space(S: int, D: int, dtype_bytes: int = 2,
 
     space = SearchSpace(params=[Param("block_q", blocks(S)),
                                 Param("block_k", blocks(S))])
-    # q block + k/v blocks + f32 scratch must fit VMEM
-    space.constraints.append(lambda c: (
-        (c["block_q"] + 2 * c["block_k"]) * D * dtype_bytes
-        + c["block_q"] * (2 * 128 + D) * 4) <= vmem_bytes // 2)
+    # q, k, v and out blocks are double-buffered; the f32 m/l/acc scratch,
+    # the f32 copies of q/k/v and the f32 (block_q, block_k) scores and
+    # probabilities are allocated once
+    space.constraints.append(lambda c: fits_vmem(
+        blocks=2 * (c["block_q"] + c["block_k"]) * D * dtype_bytes,
+        scratch=(c["block_q"] * (2 * 128 + 2 * D)
+                 + 2 * c["block_k"] * D
+                 + 2 * c["block_q"] * c["block_k"]) * 4))
     return space
 
 

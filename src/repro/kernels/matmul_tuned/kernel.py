@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import tpu_compiler_params
+
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     k = pl.program_id(2)
@@ -53,6 +55,7 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=tpu_compiler_params(),
         interpret=interpret,
     )(a, b)
 

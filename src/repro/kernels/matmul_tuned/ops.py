@@ -18,14 +18,15 @@ import jax.numpy as jnp
 
 from ...core.search_space import Param, SearchSpace
 from ...tune import autotune
-from ..common import resolve_interpret, time_fn
+from ..common import fits_vmem, resolve_interpret, time_fn
 from .kernel import matmul
 from .ref import matmul_ref
 
 
-def tuning_space(M: int, N: int, K: int, dtype_bytes: int = 2,
-                 vmem_bytes: int = 64 * 2**20) -> SearchSpace:
-    """Block lattices: MXU-aligned powers of two dividing the problem."""
+def tuning_space(M: int, N: int, K: int, dtype_bytes: int = 2
+                 ) -> SearchSpace:
+    """Block lattices: MXU-aligned powers of two dividing the problem,
+    whose VMEM footprint fits the kernels' scoped limit."""
 
     def divisors_pow2(dim: int, lo: int) -> tuple[int, ...]:
         vals = []
@@ -41,10 +42,13 @@ def tuning_space(M: int, N: int, K: int, dtype_bytes: int = 2,
         Param("bn", divisors_pow2(N, 128)),
         Param("bk", divisors_pow2(K, 128)),
     ])
-    # VMEM residency: a-block + b-block + f32 accumulator + out block
-    space.constraints.append(lambda c: (
-        (c["bm"] * c["bk"] + c["bk"] * c["bn"]) * dtype_bytes
-        + c["bm"] * c["bn"] * (4 + dtype_bytes)) <= vmem_bytes // 2)
+    # a, b and out blocks are double-buffered; the compiler stages one
+    # more copy of the a block for the MXU, and the f32 accumulator and
+    # the f32 product of one k step are allocated once
+    space.constraints.append(lambda c: fits_vmem(
+        blocks=(c["bm"] * c["bk"] + c["bk"] * c["bn"]
+                + c["bm"] * c["bn"]) * dtype_bytes,
+        scratch=c["bm"] * c["bk"] * dtype_bytes + 2 * c["bm"] * c["bn"] * 4))
     return space
 
 

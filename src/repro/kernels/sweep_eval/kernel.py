@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...core.wave_model import WaveParams
+from ..common import tpu_compiler_params
 
 SENTINEL = jnp.iinfo(jnp.int32).max
 
@@ -86,6 +87,7 @@ def sweep_eval_rows(wg: jax.Array, ts: jax.Array, p: WaveParams, *,
                   pl.BlockSpec((block_rows, 128), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.int32),
+        compiler_params=tpu_compiler_params(),
         interpret=interpret,
     )(wg, ts)
 

@@ -15,21 +15,26 @@ import jax.numpy as jnp
 from ...core.search_space import Param, SearchSpace
 from ...core.wave_model import WaveParams
 from ...tune import autotune
-from ..common import resolve_interpret, time_fn
+from ..common import fits_vmem, resolve_interpret, time_fn
 from .kernel import SENTINEL, sweep_eval_rows
 from .ref import sweep_ref
 
 _LANES = 128
+# tile-sized int32 temporaries the wave-model body keeps live at once: the
+# TPU compiler needed 13-14 of them at 256-2048 rows, so 16 bounds it
+_TEMPS = 16
 
 
-def tuning_space(n: int, vmem_bytes: int = 64 * 2**20) -> SearchSpace:
+def tuning_space(n: int) -> SearchSpace:
     """block_rows lattice: powers of two up to the (padded) data or the
-    VMEM bound — three int32 streams (WG, TS in; time out) per tile."""
+    VMEM bound — three double-buffered int32 streams (WG, TS in; time
+    out) per tile, plus the body's tile-sized int32 temporaries."""
 
     rows_total = max(8, -(-n // _LANES))
     vals = []
     r = 8
-    while r <= max(8, rows_total) and 3 * r * _LANES * 4 <= vmem_bytes // 2:
+    while r <= max(8, rows_total) and fits_vmem(
+            blocks=3 * r * _LANES * 4, scratch=_TEMPS * r * _LANES * 4):
         vals.append(r)
         r *= 2
     return SearchSpace(params=[Param("block_rows", tuple(vals) or (8,))])

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..common import tpu_compiler_params
+
 
 def _identity(op: str, dtype) -> jnp.ndarray:
     if op == "sum":
@@ -79,6 +81,7 @@ def reduce_rows(x: jax.Array, *, block_rows: int = 256, op: str = "min",
         in_specs=[pl.BlockSpec((block_rows, 128), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((8, 128), x.dtype),
+        compiler_params=tpu_compiler_params(),
         interpret=interpret,
     )(x)
 

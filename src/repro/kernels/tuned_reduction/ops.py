@@ -21,22 +21,23 @@ import jax.numpy as jnp
 
 from ...core.search_space import Param, SearchSpace
 from ...tune import autotune
-from ..common import resolve_interpret, time_fn
+from ..common import fits_vmem, resolve_interpret, time_fn
 from .kernel import _combine, _identity, reduce_rows
 from .ref import reduce_ref
 
 _LANES = 128
 
 
-def tuning_space(n: int, vmem_bytes: int = 64 * 2**20,
-                 dtype_bytes: int = 4) -> SearchSpace:
-    """block_rows lattice: powers of two that (a) keep the tile in VMEM
-    and (b) do not exceed the data."""
+def tuning_space(n: int, dtype_bytes: int = 4) -> SearchSpace:
+    """block_rows lattice: powers of two that (a) keep the double-buffered
+    tile and the (8, 128) accumulator in VMEM and (b) do not exceed the
+    data."""
 
     rows_total = max(8, n // _LANES)
     vals = []
     r = 8
-    while r <= rows_total and r * _LANES * dtype_bytes <= vmem_bytes // 2:
+    while r <= rows_total and fits_vmem(
+            blocks=(r + 8) * _LANES * dtype_bytes):
         vals.append(r)
         r *= 2
     return SearchSpace(params=[Param("block_rows", tuple(vals) or (8,))])

@@ -20,9 +20,13 @@ from ..runtime.serve import Server
 from ..runtime.speculate import DRAFTER_KINDS, spec_depth_tunable
 from ..runtime.tunables import (decode_batch_tunable, kv_page_tunable,
                                 prefill_chunk_tunable, scheduler_tunable)
+from .compile_cache import use_compile_cache
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> Server:
+    """Serve seeded requests to completion; returns the drained server."""
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--preset", choices=["smoke", "full"], default="smoke")
@@ -230,6 +234,7 @@ def main(argv=None) -> None:
             print(obs.profiler.format())
         if args.metrics and obs.registry is not None:
             print(obs.registry.to_prometheus(), end="")
+    return server
 
 
 if __name__ == "__main__":

@@ -31,9 +31,11 @@ from ..data import DataConfig, SyntheticLM
 from ..models import build_model
 from ..runtime import (LoopConfig, SimulatedFailure, TrainConfig,
                        build_train_step, init_train_state, run_training)
+from .compile_cache import use_compile_cache
 
 
 def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--preset", choices=["smoke", "full"], default="smoke")

@@ -6,7 +6,9 @@ Pure-JAX math by default (XLA fuses this well on TPU); the Pallas flash
 kernel (`repro.kernels.flash_attention`) is the opt-in runtime path via
 ``use_flash=True`` — block sizes resolve through ``@autotune`` and the
 persistent tuning cache, interpret mode keeps it runnable on CPU, and
-shapes the kernel cannot tile fall back to the pure-JAX math.
+shapes the kernel cannot tile take the pure-JAX math.  The choice
+depends on the shape alone, never on the backend: on a TPU every
+tileable call runs the compiled kernel.
 """
 
 from __future__ import annotations
@@ -25,10 +27,15 @@ NEG_INF = -1e30
 
 def attn_specs(cfg: ArchConfig, cross: bool = False) -> dict:
     d, H, Hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    # q/k/v contract over d_model, so that is their fan-in (the default,
+    # the last dim, is the head dim: q·k logits came out ~(d/hd)x too
+    # large and a random model near-hard attention, chaotic under
+    # rounding — bf16 paths then disagreed on almost every token)
+    proj = d ** -0.5
     specs: dict[str, Any] = {
-        "wq": PSpec((d, H, hd), ("embed", "heads", None)),
-        "wk": PSpec((d, Hkv, hd), ("embed", "kv_heads", None)),
-        "wv": PSpec((d, Hkv, hd), ("embed", "kv_heads", None)),
+        "wq": PSpec((d, H, hd), ("embed", "heads", None), scale=proj),
+        "wk": PSpec((d, Hkv, hd), ("embed", "kv_heads", None), scale=proj),
+        "wv": PSpec((d, Hkv, hd), ("embed", "kv_heads", None), scale=proj),
         "wo": PSpec((H, hd, d), ("heads", None, "embed")),
     }
     if cfg.qkv_bias and not cross:
@@ -114,10 +121,11 @@ def _sdpa_qchunked(q, k, v, positions, scale, *, causal, window,
 
 def _flash_supported(S: int) -> bool:
     """Can the Pallas flash kernel serve this full-sequence call?  The
-    kernel tiles S into >=128 blocks (S must divide) and lowers for TPU
-    — interpret mode covers CPU; other backends fall back."""
+    kernel tiles S into >=128 blocks, so S must divide by 128.  The
+    backend plays no part: the kernel compiles for a TPU and runs in
+    interpret mode on the CPU."""
 
-    return S % 128 == 0 and jax.default_backend() in ("cpu", "tpu")
+    return S % 128 == 0
 
 
 def _positions_standard(positions: jax.Array, S: int) -> bool:
@@ -150,8 +158,8 @@ def attention(p: dict, cfg: ArchConfig, x: jax.Array, positions: jax.Array,
     Pallas flash kernel (block sizes from the tuning cache; interpret
     mode on CPU).  The kernel derives its mask from absolute 0-based
     query/key indices, so the flash path requires the standard
-    contiguous ``positions == arange(S)`` of train/prefill; unsupported
-    shapes/backends fall back to the pure-JAX math.
+    contiguous ``positions == arange(S)`` of train/prefill; untileable
+    shapes take the pure-JAX math.
     """
 
     B, S, d = x.shape

@@ -287,7 +287,9 @@ def hidden_lm(params: dict, cfg: ArchConfig, tokens: jax.Array,
 def _chunked_ce(params: dict, cfg: ArchConfig, h: jax.Array,
                 labels: jax.Array, chunk: int) -> jax.Array:
     """CE without materializing (B, S, V): lax.map over sequence chunks
-    (memory-term optimization; numerically identical to the fused CE)."""
+    (memory-term optimization; numerically identical to the fused CE —
+    the per-token losses are averaged in one (B, S) mean, as there, not
+    summed per chunk, which reassociates)."""
 
     B, S, d = h.shape
     nc = -(-S // chunk)
@@ -297,12 +299,11 @@ def _chunked_ce(params: dict, cfg: ArchConfig, h: jax.Array,
         labels = jnp.pad(labels, ((0, 0), (0, pad)))
     hc = h.reshape(B, nc, chunk, d).swapaxes(0, 1)       # (nc, B, chunk, d)
     lc_ = labels.reshape(B, nc, chunk).swapaxes(0, 1)
-    valid = (jnp.arange(nc * chunk) < S).reshape(nc, 1, chunk)
 
     w = params["embed"] if cfg.tie_embeddings else params["unembed"]
 
     def one(args):
-        hx, lx, vx = args
+        hx, lx = args
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", hx, w)
         else:
@@ -310,10 +311,11 @@ def _chunked_ce(params: dict, cfg: ArchConfig, h: jax.Array,
         logits = logits.astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lx[..., None], axis=-1)[..., 0]
-        return jnp.sum((lse - gold) * vx)
+        return lse - gold                                # (B, chunk)
 
-    sums = jax.lax.map(one, (hc, lc_, valid))
-    return jnp.sum(sums) / (B * S)
+    per_token = jax.lax.map(one, (hc, lc_))              # (nc, B, chunk)
+    per_token = per_token.swapaxes(0, 1).reshape(B, nc * chunk)[:, :S]
+    return jnp.mean(per_token)
 
 
 def lm_loss(params: dict, cfg: ArchConfig, batch: dict) -> jax.Array:

@@ -50,19 +50,8 @@ def platform_fingerprint() -> dict[str, str]:
     under a calibration artifact — so configs tuned against calibrated
     cost models never collide with default-constant entries."""
 
-    try:
-        import jax
-        dev = jax.devices()[0]
-        fp = {"backend": jax.default_backend(),
-              "device_kind": str(getattr(dev, "device_kind", "unknown"))}
-    except Exception:                                  # pragma: no cover
-        fp = {"backend": "unknown", "device_kind": "unknown"}
-    try:
-        from ..calibrate.spec import calibration_hash
-        fp["calibration"] = calibration_hash()
-    except Exception:                                  # pragma: no cover
-        fp["calibration"] = "default"
-    return fp
+    from ..calibrate.spec import calibration_hash, device_fingerprint
+    return {**device_fingerprint(), "calibration": calibration_hash()}
 
 
 def tunable_fingerprint(tunable) -> dict[str, Any]:

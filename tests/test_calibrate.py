@@ -152,6 +152,51 @@ def test_no_artifact_falls_back_to_default(
     assert get_platform_spec() is DEFAULT_SPEC
 
 
+def _on_device(monkeypatch, tmp_path, backend, kind):
+    monkeypatch.setattr("repro.calibrate.spec.device_fingerprint",
+                        lambda: {"backend": backend, "device_kind": kind})
+    monkeypatch.setenv("REPRO_PLATFORM_SPEC", str(tmp_path / "none.json"))
+    set_platform_spec(None)
+
+
+def test_v5e_kind_as_jax_reports_it_resolves_to_v5e_peaks(
+        restore_spec, tmp_path, monkeypatch):
+    """JAX names the v5e "TPU v5 lite"; the datasheet table is keyed by
+    that string."""
+
+    _on_device(monkeypatch, tmp_path, "tpu", "TPU v5 lite")
+    spec = get_platform_spec()
+    assert spec.device_kind == "TPU v5 lite"
+    assert (spec.peak_flops, spec.hbm_bw) == (197e12, 819e9)
+
+
+def test_unknown_tpu_kind_raises(restore_spec, tmp_path, monkeypatch):
+    """An accelerator with no table entry is an error, never v5e numbers
+    — and the tuning cache cannot key an entry to it either."""
+
+    from repro.calibrate import UnknownDeviceError
+    from repro.tune.cache import platform_fingerprint
+    _on_device(monkeypatch, tmp_path, "tpu", "TPU v99")
+    with pytest.raises(UnknownDeviceError, match="TPU v99"):
+        get_platform_spec()
+    with pytest.raises(UnknownDeviceError):
+        platform_fingerprint()
+
+
+def test_device_discovery_errors_propagate(monkeypatch):
+    """A backend that fails to start is not reported as "unknown"."""
+
+    from repro.tune.cache import platform_fingerprint
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+    monkeypatch.setattr("jax.devices", broken)
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        device_fingerprint()
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        platform_fingerprint()
+
+
 # -- tuning-cache keying: calibrated never collides with default ------------
 
 
