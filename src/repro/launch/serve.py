@@ -67,11 +67,11 @@ def main(argv=None) -> Server:
     ap.add_argument("--metrics", action="store_true",
                     help="print the drain's metrics registry as "
                          "Prometheus text exposition")
-    ap.add_argument("--profile", action="store_true",
-                    help="per-tick phase breakdown (prefill vs decode "
-                         "vs speculate vs COW vs host); syncs the "
-                         "device per phase, so the drain itself runs "
-                         "slower")
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="write a jax.profiler trace of the drain to DIR: "
+                         "the engine's serve.* host spans and the "
+                         "device's ops on one timeline (TensorBoard / "
+                         "Perfetto, or jax.profiler.ProfileData)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--tune-batch", action="store_true",
                     help="pick the slot count via repro.tune")
@@ -169,10 +169,9 @@ def main(argv=None) -> Server:
         share_prefix = share_prefix or scheduler == "prefix"
 
     obs = None
-    if args.trace or args.metrics or args.profile:
+    if args.trace or args.metrics:
         from ..obs import Observability
-        obs = Observability(trace=args.trace is not None or args.profile,
-                            metrics=True, profile=args.profile,
+        obs = Observability(trace=args.trace is not None, metrics=True,
                             monitor=paged)
     server = Server(api, params, batch=batch, context=args.context,
                     prefill_chunk=prefill_chunk, paged=paged,
@@ -185,14 +184,20 @@ def main(argv=None) -> Server:
         prompt = rng.integers(0, cfg.vocab, args.prompt_len).tolist()
         server.submit(prompt, max_new=args.max_new)
 
+    if args.profile:
+        jax.profiler.start_trace(args.profile)
     t0 = time.perf_counter()
     ticks = 0
-    while server.queue or any(r is not None for r in server.slot_req):
-        server.tick()
-        ticks += 1
-        if ticks > 100_000:
-            raise RuntimeError("did not drain")
-    wall = time.perf_counter() - t0
+    try:
+        while server.queue or any(r is not None for r in server.slot_req):
+            server.tick()
+            ticks += 1
+            if ticks > 100_000:
+                raise RuntimeError("did not drain")
+        wall = time.perf_counter() - t0
+    finally:
+        if args.profile:
+            jax.profiler.stop_trace()
 
     done = server.completed
     total_tokens = sum(len(r.out) for r in done)
@@ -221,6 +226,9 @@ def main(argv=None) -> Server:
               f"ticks/token={st['ticks_per_token']:.2f}")
     for r in done[:3]:
         print(f"  req{r.rid}: prompt={r.prompt[:4]}... out={r.out}")
+    if args.profile:
+        print(f"  profile: jax.profiler trace of the drain -> "
+              f"{args.profile}")
     if obs is not None:
         doc = obs.export(args.trace)
         if args.trace:
@@ -230,8 +238,6 @@ def main(argv=None) -> Server:
             mon = doc["monitor"]
             print(f"  conformance monitor: {mon['status']} "
                   f"({mon['ops_checked']} allocator ops checked)")
-        if args.profile and obs.profiler is not None:
-            print(obs.profiler.format())
         if args.metrics and obs.registry is not None:
             print(obs.registry.to_prometheus(), end="")
     return server

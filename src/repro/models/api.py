@@ -173,7 +173,8 @@ class ModelAPI:
                   params["xattn_blocks"], state["xattn"])
         else:
             xs = (params["blocks"], state["blocks"])
-        x, new_blocks = jax.lax.scan(body, x, xs)
+        with jax.named_scope("layers"):
+            x, new_blocks = jax.lax.scan(body, x, xs)
         logits = _logits(params, cfg, x)[:, 0]
         new_state = dict(state)
         new_state["blocks"] = new_blocks
@@ -257,7 +258,8 @@ class ModelAPI:
                   params["xattn_blocks"], state["xattn"])
         else:
             xs = (params["blocks"], state["blocks"])
-        x, new_blocks = jax.lax.scan(body, x, xs)
+        with jax.named_scope("layers"):
+            x, new_blocks = jax.lax.scan(body, x, xs)
         new_state = dict(state)
         new_state["blocks"] = new_blocks
         return x, new_state, lengths

@@ -384,9 +384,10 @@ def _gather_pool(pool_kv: jax.Array, page_table: jax.Array) -> jax.Array:
     through the page table (unallocated entries gather page 0 — callers
     mask them by ``page_table >= 0``)."""
 
-    g = pool_kv[jnp.clip(page_table, 0)]          # (B, M, Hkv, ps, hd)
-    B, M, Hkv, ps, hd = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * ps, hd)
+    with jax.named_scope("kv_read"):
+        g = pool_kv[jnp.clip(page_table, 0)]      # (B, M, Hkv, ps, hd)
+        B, M, Hkv, ps, hd = g.shape
+        return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * ps, hd)
 
 
 def _pool_validity(page_table: jax.Array, page_size: int) -> jax.Array:
@@ -405,12 +406,15 @@ def _grouped_sdpa(q: jax.Array, k: jax.Array, v: jax.Array,
     B, H, T, hd = q.shape
     Hkv = k.shape[1]
     g = H // Hkv
-    qg = q.reshape(B, Hkv, g, T, hd).astype(jnp.float32) * scale
-    s = jnp.einsum("bkgtd,bksd->bkgts", qg, k.astype(jnp.float32))
-    s = jnp.where(mask, s, NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-    og = jnp.einsum("bkgts,bksd->bkgtd", pr, v.astype(jnp.float32))
-    return og.reshape(B, H, T, hd)
+    with jax.named_scope("kv_read"):
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+    with jax.named_scope("attend"):
+        qg = q.reshape(B, Hkv, g, T, hd).astype(jnp.float32) * scale
+        s = jnp.einsum("bkgtd,bksd->bkgts", qg, k)
+        s = jnp.where(mask, s, NEG_INF)
+        pr = jax.nn.softmax(s, axis=-1)
+        og = jnp.einsum("bkgts,bksd->bkgtd", pr, v)
+        return og.reshape(B, H, T, hd)
 
 
 def decode_attention_paged(p: dict, cfg: ArchConfig, x: jax.Array,
@@ -440,46 +444,49 @@ def decode_attention_paged(p: dict, cfg: ArchConfig, x: jax.Array,
     if active is None:
         active = jnp.ones((B,), bool)
     positions = cur_len[:, None]                  # (B, 1)
-    q, k_new, v_new = _project_qkv(p, cfg, x, x)
-    if cfg.use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k_new, v_new = _project_qkv(p, cfg, x, x)
+        if cfg.use_rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k_new = rope(k_new, positions, cfg.rope_theta)
 
     # scatter: slot b writes page page_table[b, pos//ps] offset pos%ps.
     # Pages are slot-exclusive (allocator invariant), so at most one
     # slot writes any (page, offset); one-hot + argmax keeps the update
     # elementwise under pool sharding, as in decode_attention.
-    lp = jnp.clip(cur_len // ps, 0, M - 1)        # (B,) logical page
-    off = cur_len % ps                            # (B,)
-    phys = jnp.take_along_axis(page_table, lp[:, None], axis=1)[:, 0]
-    phys = jnp.where(active, phys, -1)            # inactive: never match
-    hot = (jnp.arange(P)[None, :] == phys[:, None])[:, :, None] \
-        & (jnp.arange(ps)[None, :] == off[:, None])[:, None, :]  # (B,P,ps)
-    written = hot.any(axis=0)                     # (P, ps)
-    writer = jnp.argmax(hot, axis=0)              # (P, ps) -> slot index
+    with jax.named_scope("kv_write"):
+        lp = jnp.clip(cur_len // ps, 0, M - 1)    # (B,) logical page
+        off = cur_len % ps                        # (B,)
+        phys = jnp.take_along_axis(page_table, lp[:, None], axis=1)[:, 0]
+        phys = jnp.where(active, phys, -1)        # inactive: never match
+        hot = (jnp.arange(P)[None, :] == phys[:, None])[:, :, None] \
+            & (jnp.arange(ps)[None, :] == off[:, None])[:, None, :]
+        written = hot.any(axis=0)                 # (P, ps)
+        writer = jnp.argmax(hot, axis=0)          # (P, ps) -> slot index
 
-    def scatter(new, old):                        # new: (B, Hkv, 1, hd)
-        vals = new[writer, :, 0, :].transpose(0, 2, 1, 3)  # (P,Hkv,ps,hd)
-        return jnp.where(written[:, None, :, None], vals.astype(old.dtype),
-                         old)
+        def scatter(new, old):                    # new: (B, Hkv, 1, hd)
+            vals = new[writer, :, 0, :].transpose(0, 2, 1, 3)
+            return jnp.where(written[:, None, :, None],
+                             vals.astype(old.dtype), old)
 
-    new_pool = {"k": scatter(k_new, pool["k"]),
-                "v": scatter(v_new, pool["v"])}
+        new_pool = {"k": scatter(k_new, pool["k"]),
+                    "v": scatter(v_new, pool["v"])}
 
     # gather the slot's linear view from the UPDATED pool; position t
     # lives at page t//ps — validity is the same absolute-position mask
     # as the contiguous path plus "is the page live"
     k = _gather_pool(new_pool["k"], page_table)   # (B, Hkv, M*ps, hd)
     v = _gather_pool(new_pool["v"], page_table)
-    t = jnp.arange(M * ps, dtype=jnp.int32)[None, :]        # (1, M*ps)
-    cl = cur_len[:, None]                                   # (B, 1)
-    valid = (t <= cl) & _pool_validity(page_table, ps)
-    if window is not None:
-        valid &= t >= cl - window + 1
-    mask = valid[:, None, None, None, :]          # (B, 1, 1, 1, M*ps)
+    with jax.named_scope("attend"):
+        t = jnp.arange(M * ps, dtype=jnp.int32)[None, :]    # (1, M*ps)
+        cl = cur_len[:, None]                               # (B, 1)
+        valid = (t <= cl) & _pool_validity(page_table, ps)
+        if window is not None:
+            valid &= t >= cl - window + 1
+        mask = valid[:, None, None, None, :]      # (B, 1, 1, 1, M*ps)
 
-    o = _grouped_sdpa(q, k, v, mask, cfg.hd ** -0.5).astype(x.dtype)
-    out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+        o = _grouped_sdpa(q, k, v, mask, cfg.hd ** -0.5).astype(x.dtype)
+        out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
     return lc(out, "batch", "seq", "embed"), new_pool
 
 
@@ -507,32 +514,35 @@ def decode_attention_chunked_paged(p: dict, cfg: ArchConfig, x: jax.Array,
     pos = cur_len[:, None] + t_idx[None, :]            # (B, T) absolute
     valid = t_idx[None, :] < lengths[:, None]          # (B, T)
 
-    q, k_new, v_new = _project_qkv(p, cfg, x, x)
-    if cfg.use_rope:
-        q = rope(q, pos, cfg.rope_theta)
-        k_new = rope(k_new, pos, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k_new, v_new = _project_qkv(p, cfg, x, x)
+        if cfg.use_rope:
+            q = rope(q, pos, cfg.rope_theta)
+            k_new = rope(k_new, pos, cfg.rope_theta)
 
     # scatter: chunk token (b, t) -> page page_table[b, pos//ps], offset
     # pos%ps; valid tokens only.  Positions are unique and pages
     # slot-exclusive, so at most one (b, t) writes any (page, offset).
-    lp = jnp.clip(pos // ps, 0, M - 1)                 # (B, T)
-    off = pos % ps
-    phys = jnp.take_along_axis(page_table, lp, axis=1)  # (B, T)
-    phys = jnp.where(valid, phys, -1)
-    hot = (phys[:, :, None] == jnp.arange(P)[None, None, :])[..., None] \
-        & (off[:, :, None] == jnp.arange(ps)[None, None, :])[:, :, None, :]
-    hot = hot.reshape(B * T, P, ps)                    # (B*T, P, ps)
-    written = hot.any(axis=0)                          # (P, ps)
-    writer = jnp.argmax(hot, axis=0)                   # (P, ps) -> b*T+t
+    with jax.named_scope("kv_write"):
+        lp = jnp.clip(pos // ps, 0, M - 1)             # (B, T)
+        off = pos % ps
+        phys = jnp.take_along_axis(page_table, lp, axis=1)  # (B, T)
+        phys = jnp.where(valid, phys, -1)
+        hot = (phys[:, :, None] == jnp.arange(P)[None, None, :])[..., None] \
+            & (off[:, :, None]
+               == jnp.arange(ps)[None, None, :])[:, :, None, :]
+        hot = hot.reshape(B * T, P, ps)                # (B*T, P, ps)
+        written = hot.any(axis=0)                      # (P, ps)
+        writer = jnp.argmax(hot, axis=0)               # (P, ps) -> b*T+t
 
-    def scatter(new, old):                             # new: (B,Hkv,T,hd)
-        flat = new.transpose(0, 2, 1, 3).reshape(B * T, Hkv, hd)
-        vals = flat[writer].transpose(0, 2, 1, 3)      # (P, Hkv, ps, hd)
-        return jnp.where(written[:, None, :, None], vals.astype(old.dtype),
-                         old)
+        def scatter(new, old):                         # new: (B,Hkv,T,hd)
+            flat = new.transpose(0, 2, 1, 3).reshape(B * T, Hkv, hd)
+            vals = flat[writer].transpose(0, 2, 1, 3)  # (P, Hkv, ps, hd)
+            return jnp.where(written[:, None, :, None],
+                             vals.astype(old.dtype), old)
 
-    new_pool = {"k": scatter(k_new, pool["k"]),
-                "v": scatter(v_new, pool["v"])}
+        new_pool = {"k": scatter(k_new, pool["k"]),
+                    "v": scatter(v_new, pool["v"])}
 
     # chunk-causal read over the updated pool: key position t is valid
     # for query position qp when t <= qp (all such positions are
@@ -540,15 +550,16 @@ def decode_attention_chunked_paged(p: dict, cfg: ArchConfig, x: jax.Array,
     # page is live
     k = _gather_pool(new_pool["k"], page_table)        # (B, Hkv, M*ps, hd)
     v = _gather_pool(new_pool["v"], page_table)
-    kp = jnp.arange(M * ps, dtype=jnp.int32)[None, None, :]   # (1,1,M*ps)
-    qp = pos[:, :, None]                               # (B, T, 1)
-    mask = (kp <= qp) & _pool_validity(page_table, ps)[:, None, :]
-    if window is not None:
-        mask &= kp >= qp - window + 1
-    mask = mask[:, None, None, :, :]                   # (B,1,1,T,M*ps)
+    with jax.named_scope("attend"):
+        kp = jnp.arange(M * ps, dtype=jnp.int32)[None, None, :]
+        qp = pos[:, :, None]                           # (B, T, 1)
+        mask = (kp <= qp) & _pool_validity(page_table, ps)[:, None, :]
+        if window is not None:
+            mask &= kp >= qp - window + 1
+        mask = mask[:, None, None, :, :]               # (B,1,1,T,M*ps)
 
-    o = _grouped_sdpa(q, k, v, mask, cfg.hd ** -0.5).astype(x.dtype)
-    out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+        o = _grouped_sdpa(q, k, v, mask, cfg.hd ** -0.5).astype(x.dtype)
+        out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
     return lc(out, "batch", "seq", "embed"), new_pool
 
 

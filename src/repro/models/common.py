@@ -88,6 +88,7 @@ def axes_tree(tree):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("norm")
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     dt = x.dtype
     x = x.astype(jnp.float32)

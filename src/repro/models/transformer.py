@@ -51,6 +51,7 @@ def mlp_specs(cfg: ArchConfig) -> dict:
             "wd": PSpec((f, d), ("mlp", "embed"))}
 
 
+@jax.named_scope("mlp")
 def mlp_forward(p: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     if cfg.mlp_act == "gelu":
         h = jax.nn.gelu(x @ p["w1"] + p["b1"])
@@ -203,6 +204,7 @@ def _scan_blocks(cfg: ArchConfig, blocks, x, positions, *, enc_out=None,
     return x
 
 
+@jax.named_scope("head")
 def _logits(params: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["ln_f"])
     if cfg.tie_embeddings:

@@ -3,7 +3,7 @@
 * ``summarize TRACE.json`` — human-readable digest: envelope, event
   counts, span totals by name, workload latency summary (when the
   drain was driven by :func:`repro.runtime.workload.drive_trace`),
-  phase breakdown, monitor status.
+  monitor status.
 * ``check TRACE.json [--json]`` — machine gate: schema + clock
   validation via :func:`repro.obs.trace.validate_trace`, plus an
   OFFLINE re-run of the direction-2 conformance check over the
@@ -94,12 +94,6 @@ def _cmd_summarize(args: argparse.Namespace) -> int:
               f"{s['p50_all']:.0f}/{s['p99_all']:.0f} ticks; "
               f"SLO attainment {s['slo_attainment']:.1%}; "
               f"goodput {s['goodput_per_tick']:.2f} tok/tick")
-    phases = doc.get("phases")
-    if phases:
-        print("  phases (profiled, device-synced):")
-        for name, r in phases.items():
-            print(f"    {name:<12} total {r['total_us']:>10.0f} us  "
-                  f"mean {r['mean_us']:>8.1f} us  {r['share']:6.1%}")
     mon = doc.get("monitor")
     if mon:
         print(f"  monitor: {mon['status']} ({mon['ops_checked']} ops, "

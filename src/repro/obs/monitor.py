@@ -33,8 +33,10 @@ from collections import deque
 from typing import Any
 
 from ..runtime.kv import PagedKVAllocator
-from ..verify.conformance import ConformanceError, TraceChecker
-from ..verify.models import AllocConfig, AllocatorSemantics
+# module imports, not names: the serving runtime imports repro.obs for
+# its spans, and repro.verify imports the runtime, so either package may
+# be half-initialised when the other is imported first
+from ..verify import conformance, models
 
 
 def _jsonable_ret(ret: Any) -> Any:
@@ -60,12 +62,12 @@ class ConformanceMonitor:
                  max_trail: int = 200_000, strict: bool = False):
         spec = alloc.spec
         self.alloc = alloc
-        self.cfg = AllocConfig(n_slots=alloc.n_slots,
-                               page_size=spec.page_size,
-                               pages_per_slot=spec.pages_per_slot,
-                               n_pages=spec.n_pages)
-        self.sem = AllocatorSemantics(self.cfg, canonical=False)
-        self.checker = TraceChecker(self.sem)
+        self.cfg = models.AllocConfig(n_slots=alloc.n_slots,
+                                      page_size=spec.page_size,
+                                      pages_per_slot=spec.pages_per_slot,
+                                      n_pages=spec.n_pages)
+        self.sem = models.AllocatorSemantics(self.cfg, canonical=False)
+        self.checker = conformance.TraceChecker(self.sem)
         self.strict = strict
         if alloc.trace is None:
             alloc.trace = []
@@ -114,7 +116,7 @@ class ConformanceMonitor:
                 self.truncated = True
             try:
                 self.checker.feed(record)
-            except ConformanceError as exc:
+            except conformance.ConformanceError as exc:
                 return self._violate(str(exc), tick)
             self.ops_checked += 1
         divergence = self.checker.state_divergence(self.alloc)
@@ -127,7 +129,7 @@ class ConformanceMonitor:
                           "op_index": self.ops_checked,
                           "allocator": self.allocator_name}
         if self.strict:
-            raise ConformanceError(
+            raise conformance.ConformanceError(
                 f"online conformance violation (tick {tick}): {message}")
         return False
 

@@ -1,11 +1,11 @@
 """The observability bundle a :class:`~repro.runtime.serve.Server`
 publishes into.
 
-``Observability`` composes the four obs facilities behind one object
-the engine can hold and null-check: a :class:`.trace.TraceRecorder`
-(lifecycle + tick spans), a :class:`.metrics.MetricsRegistry`
-(counters/gauges/histograms), an optional :class:`.profile.PhaseProfiler`
-(per-tick phase attribution with device sync), and an optional
+``Observability`` composes the obs facilities behind one object the
+engine can hold and null-check: a :class:`.trace.TraceRecorder`
+(lifecycle + tick spans, and the engine's ``serve.*`` host spans via
+:func:`.trace.span`), a :class:`.metrics.MetricsRegistry`
+(counters/gauges/histograms), and an optional
 :class:`.monitor.ConformanceMonitor` (the online direction-2 model
 check on the paged allocator's op stream).  Construct one, pass it as
 ``Server(..., obs=...)``, drain, then :meth:`export` the combined
@@ -16,8 +16,9 @@ The engine's contract is narrow: every hook is a no-op-cheap method
 call guarded by ``if self.obs is not None`` at the call site, and NO
 hook touches device values (everything recorded is host state the
 engine already materialized), so attaching observability cannot change
-a drain's outputs.  Only ``profile=True`` alters timing, by
-``block_until_ready``-syncing each phase — a diagnosis mode.
+a drain's outputs, and nothing here waits for the device.  Device time
+per phase comes from a ``jax.profiler`` trace, where the same
+``serve.*`` spans sit on the device's clock.
 
 Tick stamps on request/slot tracks are the SERVER tick clock
 (``server.ticks``); workload-level events driven on the driver clock
@@ -28,24 +29,17 @@ monotone (the property :func:`.trace.validate_trace` enforces).
 
 from __future__ import annotations
 
-import time
-from typing import Any
-
 from .metrics import MetricsRegistry
 from .monitor import ConformanceMonitor
-from .profile import PhaseProfiler
 from .trace import TraceRecorder, export_trace
 
 
 class Observability:
     def __init__(self, *, trace: bool = True, metrics: bool = True,
-                 profile: bool = False, monitor: bool = False,
-                 monitor_window: int = 256, strict: bool = False,
-                 profile_warmup_ticks: int = 1):
+                 monitor: bool = False, monitor_window: int = 256,
+                 strict: bool = False):
         self.recorder = TraceRecorder() if trace else None
         self.registry = MetricsRegistry() if metrics else None
-        self.profiler = (PhaseProfiler(warmup_ticks=profile_warmup_ticks)
-                         if profile else None)
         self._want_monitor = monitor
         self._strict = strict
         self._monitor_window = monitor_window
@@ -151,11 +145,9 @@ class Observability:
                 "submit -> retire, in engine ticks",
                 slo=req.slo).observe(latency)
 
-    # -- tick + phase hooks ------------------------------------------------
+    # -- tick hooks --------------------------------------------------------
 
     def on_tick_begin(self, server, tick: int) -> None:
-        if self.profiler:
-            self.profiler.tick_begin()
         if self.recorder:
             self.recorder.begin("tick", tick=tick)
 
@@ -191,21 +183,6 @@ class Observability:
                     self.registry.counter(
                         "serve.conformance_violations",
                         "online monitor trips").inc()
-        if self.profiler:
-            self.profiler.tick_end()
-
-    def phase_begin(self, name: str, tick: int) -> float:
-        t0 = time.perf_counter()
-        if self.recorder:
-            self.recorder.begin(f"phase.{name}", tick=tick)
-        return t0
-
-    def phase_end(self, name: str, tick: int, t0: float, sync=None,
-                  **args: Any) -> None:
-        if self.profiler:
-            self.profiler.phase_end(name, t0, sync=sync)
-        if self.recorder:
-            self.recorder.end(f"phase.{name}", tick=tick, **args)
 
     # -- export ------------------------------------------------------------
 
@@ -225,7 +202,6 @@ class Observability:
             events, path,
             metrics=(self.registry.snapshot() if self.registry
                      else None),
-            phases=(self.profiler.report() if self.profiler else None),
             monitor=(self.monitor.report() if self.monitor
                      else None))
 

@@ -7,8 +7,9 @@ engine tick (the deterministic clock every latency summary and model
 check runs on) and wall-clock microseconds (what Perfetto renders).
 Events live on *tracks*:
 
-* ``("engine",)`` — per-tick spans and their nested phase spans
-  (decode / speculate / verify / prefill / COW copies);
+* ``("engine",)`` — per-tick spans and the engine's ``serve.*`` host
+  spans (:func:`span`: admission, page backing, and per step the
+  input packing, dispatch, token read-back and commit);
 * ``("slot", s)`` — slot occupancy: one span per residency of a
   request in slot ``s``;
 * ``("request", rid)`` — the request lifecycle: an outer ``request``
@@ -18,7 +19,7 @@ Events live on *tracks*:
 
 :func:`export_trace` writes a single JSON document that is BOTH the
 schema'd artifact (``kind``/``schema``/``meta`` envelope, optional
-``metrics``/``phases``/``monitor`` sections) and directly loadable by
+``metrics``/``monitor`` sections) and directly loadable by
 Perfetto / ``chrome://tracing`` — those readers use the standard
 ``traceEvents`` key and ignore the extra top-level keys.  Tracks map to
 pid/tid: pid 1 is the engine process (tid 0 the tick timeline, tid
@@ -36,6 +37,8 @@ import os
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
+
+from jax.profiler import TraceAnnotation
 
 TRACE_KIND = "repro.obs/trace"
 TRACE_SCHEMA = 1
@@ -116,6 +119,46 @@ class TraceRecorder:
         return n
 
 
+def span(name: str, obs: Any = None, tick: int = 0, **args: Any):
+    """Context manager for one host span of the serving engine.
+
+    It always enters ``jax.profiler.TraceAnnotation(name)``, so the span
+    lands in a running profiler trace on the device trace's own clock
+    (and costs one annotation round trip when no profiler runs).  With
+    an attached ``Observability`` that has a recorder, the same span is
+    also recorded on its :class:`TraceRecorder` (engine track, ``tick``
+    and ``args`` on the begin event)."""
+
+    rec = obs.recorder if obs is not None else None
+    if rec is None:
+        return TraceAnnotation(name)
+    return _RecordedSpan(name, rec, tick, args)
+
+
+class _RecordedSpan:
+    """A profiler annotation that is also a recorder span.  Its end takes
+    the track's latest tick, so a span that outlives a tick increment
+    (``serve.tick``) keeps the tick clock monotone."""
+
+    __slots__ = ("_ann", "_rec", "_name", "_tick", "_args")
+
+    def __init__(self, name: str, rec: TraceRecorder, tick: int,
+                 args: dict):
+        self._ann = TraceAnnotation(name)
+        self._rec, self._name, self._tick, self._args = rec, name, tick, args
+
+    def __enter__(self) -> "_RecordedSpan":
+        self._ann.__enter__()
+        self._rec.begin(self._name, tick=self._tick, **self._args)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        rec = self._rec
+        rec.end(self._name,
+                tick=max(self._tick, rec._last_tick.get(ENGINE, 0)))
+        self._ann.__exit__(*exc)
+
+
 # -- export / parse ---------------------------------------------------------
 
 def _track_pid_tid(track: tuple) -> tuple[int, int]:
@@ -168,7 +211,6 @@ def chrome_events(events: Iterable[dict]) -> list[dict]:
 
 def export_trace(events: Iterable[dict], path: str | None = None, *,
                  metrics: dict | None = None,
-                 phases: dict | None = None,
                  monitor: dict | None = None,
                  meta: dict | None = None) -> dict:
     """Build (and optionally atomically write) the trace document."""
@@ -182,8 +224,6 @@ def export_trace(events: Iterable[dict], path: str | None = None, *,
     }
     if metrics is not None:
         doc["metrics"] = metrics
-    if phases is not None:
-        doc["phases"] = phases
     if monitor is not None:
         doc["monitor"] = monitor
     if path is not None:
@@ -323,5 +363,5 @@ def validate_trace(doc: dict) -> list[str]:
 
 
 __all__ = ["TRACE_KIND", "TRACE_SCHEMA", "ENGINE", "TraceRecorder",
-           "Span", "chrome_events", "export_trace", "parse_trace",
+           "span", "Span", "chrome_events", "export_trace", "parse_trace",
            "spans_from_events", "validate_trace"]

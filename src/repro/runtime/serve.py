@@ -83,6 +83,7 @@ drafter is the ``serve.spec_depth`` tunable
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -91,6 +92,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.api import ModelAPI
+from ..obs.trace import span
 from .kv import NO_PAGE, PagedKVAllocator, PagedKVSpec
 from .scheduler import Scheduler, make_scheduler
 
@@ -129,6 +131,10 @@ class Request:
     shared_prefix: int = 0      # tokens admitted via COW page sharing
     spec_proposed: int = 0      # draft tokens verified for this request
     spec_accepted: int = 0      # of those, accepted into the output
+    # lifecycle stamps on time.perf_counter(): submission, and the first
+    # placement into a slot (a preempted request keeps its first)
+    t_submitted: float | None = None
+    t_admitted: float | None = None
 
 
 class Server:
@@ -330,6 +336,7 @@ class Server:
                       prompt=prompt, max_new=max_new, slo=slo,
                       deadline=deadline)
         req._frames = frames  # type: ignore[attr-defined]
+        req.t_submitted = time.perf_counter()
         self.queue.append(req)
         if self.obs is not None:
             self.obs.on_submit(self, req)
@@ -416,6 +423,8 @@ class Server:
         off."""
 
         self.slot_req[slot] = req
+        if req.t_admitted is None:
+            req.t_admitted = time.perf_counter()
         self._slot_seq[slot] = self._seq
         self._seq += 1
         if self._slot_dirty[slot]:
@@ -689,84 +698,137 @@ class Server:
         (admission order) and breaks COW sharing for every position
         about to be written; a slot the allocator cannot cover — even
         after evicting every policy-offered victim — is itself evicted
-        and sits the tick out."""
+        and sits the tick out.
 
-        self._admit()
-        drafts = self._propose_drafts()
+        The host spans (:func:`~repro.obs.trace.span`) have fixed
+        names: ``serve.tick`` around the whole body; ``serve.admit``,
+        ``serve.draft`` (with a drafter), ``serve.back`` (paged backing,
+        evictions, COW copies), ``serve.trim`` (window reclamation); and
+        per step run — ``decode``, ``verify``, ``accept`` (the
+        speculative commit) or ``prefill`` — ``serve.pack.<step>`` (host
+        inputs and their uploads), ``serve.dispatch.<step>`` (the jitted
+        call), ``serve.readback.<step>`` (argmax to the host) and
+        ``serve.commit.<step>`` (per-slot bookkeeping)."""
+
+        with span("serve.tick", self.obs, self.ticks):
+            return self._tick()
+
+    def _tick(self) -> int:
+        ob = self.obs
+        with span("serve.admit", ob, self.ticks):
+            self._admit()
+        drafts: dict[int, list[int]] = {}
+        if self.drafter is not None:
+            with span("serve.draft", ob, self.ticks):
+                drafts = self._propose_drafts()
         if self.paged:
-            cow_pairs: list[tuple[int, int]] = []
-            order = sorted((s for s in range(self.batch)
-                            if self.slot_req[s] is not None),
-                           key=lambda s: self._slot_seq[s])
-            for s in order:
-                req = self.slot_req[s]
-                if req is None:          # evicted as an earlier victim
-                    continue
-                pos = int(self.slot_pos[s])
-                if self._phase(s) == "decode":
-                    end = pos + 1
-                    if s in drafts:
-                        # opportunistic draft backing: shrink the draft
-                        # to whatever the free list covers WITHOUT
-                        # evicting a neighbour — speculation must
-                        # never evict a slot a plain decode wouldn't
-                        dr = drafts.pop(s)
-                        for dd in range(len(dr), 0, -1):
-                            if self.alloc.ensure(s, pos + dd + 1):
-                                drafts[s] = dr[:dd]
-                                end = pos + dd + 1
-                                break
-                    if s not in drafts and \
-                            not self._ensure_pages(s, pos + 1):
-                        continue
-                else:
-                    cur = req._cursor  # type: ignore[attr-defined]
-                    n = min(self.prefill_chunk, req._prefill_target - cur)
-                    end = pos + n
-                    if not self._ensure_pages(s, end):
-                        continue
-                if self.share_prefix and self.slot_req[s] is req:
-                    cow_pairs.extend(self._cow_range(s, pos, end))
-            if cow_pairs:
-                ob = self.obs
-                t0 = (ob.phase_begin("cow_copy", self.ticks)
-                      if ob is not None else 0.0)
-                self._copy_pages(cow_pairs)
-                if ob is not None:
-                    ob.phase_end("cow_copy", self.ticks, t0,
-                                 sync=self.state, pages=len(cow_pairs))
-            self.peak_used_pages = max(self.peak_used_pages,
-                                       self.alloc.used_pages)
+            with span("serve.back", ob, self.ticks):
+                self._back(drafts)
         active = [s for s in range(self.batch) if self.slot_req[s] is not None]
         self.peak_active = max(self.peak_active, len(active))
         if not active:
             return 0
         self.ticks += 1
         self.slot_ticks += len(active)
-        ob = self.obs
         if ob is not None:
             ob.on_tick_begin(self, self.ticks)
         decode = [s for s in active if self._phase(s) == "decode"]
         spec = [s for s in decode if s in drafts]
         decode = [s for s in decode if s not in drafts]
         prefill = [s for s in active if self._phase(s) == "prefill"]
-        page_table = (_snapshot(self.alloc.page_table)
-                      if self.paged else None)
-
+        # the page table is snapshot once per tick, by the first step's
+        # pack; every step of the tick reads that one snapshot
+        table = None
         if decode:
-            if ob is not None:
-                t0 = ob.phase_begin("decode", self.ticks)
+            table = self._run_decode(decode, table)
+        if spec:
+            table = self._run_spec(spec, drafts, table)
+        if prefill:
+            self._run_prefill(prefill, table)
+
+        # sliding-window reclamation: pages whose positions all fell out
+        # of the window are never attended again — hand them back.  The
+        # next tick's earliest attended position is slot_pos - window + 1.
+        if self.paged and self.api.cfg.window is not None:
+            with span("serve.trim", ob, self.ticks):
+                w = self.api.cfg.window
+                for s in range(self.batch):
+                    if self.slot_req[s] is not None:
+                        self.alloc.trim(
+                            s, max(0, int(self.slot_pos[s]) - w + 1))
+        if ob is not None:
+            ob.on_tick_end(self, self.ticks, n_decode=len(decode),
+                           n_spec=len(spec), n_prefill=len(prefill))
+        return len(active)
+
+    def _back(self, drafts: dict[int, list[int]]) -> None:
+        """Paged backing for this tick: pages for every position a slot
+        is about to write (admission order, evicting policy-chosen
+        victims on OOM), draft positions where the free list covers them
+        without evicting, and COW breaks copied on the device."""
+
+        cow_pairs: list[tuple[int, int]] = []
+        order = sorted((s for s in range(self.batch)
+                        if self.slot_req[s] is not None),
+                       key=lambda s: self._slot_seq[s])
+        for s in order:
+            req = self.slot_req[s]
+            if req is None:          # evicted as an earlier victim
+                continue
+            pos = int(self.slot_pos[s])
+            if self._phase(s) == "decode":
+                end = pos + 1
+                if s in drafts:
+                    # opportunistic draft backing: shrink the draft to
+                    # whatever the free list covers WITHOUT evicting a
+                    # neighbour — speculation must never evict a slot a
+                    # plain decode wouldn't
+                    dr = drafts.pop(s)
+                    for dd in range(len(dr), 0, -1):
+                        if self.alloc.ensure(s, pos + dd + 1):
+                            drafts[s] = dr[:dd]
+                            end = pos + dd + 1
+                            break
+                if s not in drafts and not self._ensure_pages(s, pos + 1):
+                    continue
+            else:
+                cur = req._cursor  # type: ignore[attr-defined]
+                n = min(self.prefill_chunk, req._prefill_target - cur)
+                end = pos + n
+                if not self._ensure_pages(s, end):
+                    continue
+            if self.share_prefix and self.slot_req[s] is req:
+                cow_pairs.extend(self._cow_range(s, pos, end))
+        if cow_pairs:
+            self._copy_pages(cow_pairs)
+        self.peak_used_pages = max(self.peak_used_pages,
+                                   self.alloc.used_pages)
+
+    def _table(self, table):
+        """The tick's page-table snapshot (taken on first use)."""
+
+        if self.paged and table is None:
+            table = _snapshot(self.alloc.page_table)
+        return table
+
+    def _run_decode(self, decode: list[int], table):
+        ob, t = self.obs, self.ticks
+        with span("serve.pack.decode", ob, t):
             tokens = np.zeros((self.batch, 1), np.int32)
             mask = np.zeros(self.batch, bool)
             for s in decode:
                 tokens[s, 0] = self.slot_req[s].out[-1]
                 mask[s] = True
-            extra = (page_table,) if self.paged else ()
-            logits, self.state = self._step(self.params, self.state,
-                                            jnp.asarray(tokens),
-                                            _snapshot(self.slot_pos),
-                                            jnp.asarray(mask), *extra)
+            table = self._table(table)
+            extra = (table,) if self.paged else ()
+            tok, pos = jnp.asarray(tokens), _snapshot(self.slot_pos)
+            act = jnp.asarray(mask)
+        with span("serve.dispatch.decode", ob, t):
+            logits, self.state = self._step(self.params, self.state, tok,
+                                            pos, act, *extra)
+        with span("serve.readback.decode", ob, t):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("serve.commit.decode", ob, t, slots=len(decode)):
             for s in decode:
                 req = self.slot_req[s]
                 req._cursor += 1  # type: ignore[attr-defined]
@@ -774,19 +836,18 @@ class Server:
                 req.out.append(int(nxt[s]))
                 self.tokens_generated += 1
                 self._retire_if_done(s)
-            if ob is not None:
-                ob.phase_end("decode", self.ticks, t0, sync=self.state,
-                             slots=len(decode))
+        return table
 
-        if spec:
-            if ob is not None:
-                t0 = ob.phase_begin("speculate", self.ticks)
-            # speculation: verify the chunk [pending token, drafts...]
-            # at absolute positions pos..pos+d in one forward, accept
-            # the longest prefix of drafts matching the verifier's own
-            # greedy picks (plus its bonus token), then COMMIT exactly
-            # the accepted tokens with a length-gated prefill_step (the
-            # verify state, rejected writes included, is discarded)
+    def _run_spec(self, spec: list[int], drafts: dict[int, list[int]],
+                  table):
+        # speculation: verify the chunk [pending token, drafts...] at
+        # absolute positions pos..pos+d in one forward, accept the
+        # longest prefix of drafts matching the verifier's own greedy
+        # picks (plus its bonus token), then COMMIT exactly the accepted
+        # tokens with a length-gated prefill_step (the verify state,
+        # rejected writes included, is discarded)
+        ob, t = self.obs, self.ticks
+        with span("serve.pack.verify", ob, t):
             D1 = self.spec_depth + 1
             tokens = np.zeros((self.batch, D1), np.int32)
             lengths = np.zeros(self.batch, np.int32)
@@ -795,11 +856,16 @@ class Server:
                 tokens[s, 0] = self.slot_req[s].out[-1]
                 tokens[s, 1:1 + len(dr)] = dr
                 lengths[s] = len(dr) + 1
-            extra = (page_table,) if self.paged else ()
-            logits, _ = self._verify_step(
-                self.params, self.state, jnp.asarray(tokens),
-                _snapshot(self.slot_pos), jnp.asarray(lengths), *extra)
+            table = self._table(table)
+            extra = (table,) if self.paged else ()
+            tok, pos = jnp.asarray(tokens), _snapshot(self.slot_pos)
+            lens = jnp.asarray(lengths)
+        with span("serve.dispatch.verify", ob, t):
+            logits, _ = self._verify_step(self.params, self.state, tok, pos,
+                                          lens, *extra)
+        with span("serve.readback.verify", ob, t):
             greedy = np.asarray(jnp.argmax(logits, axis=-1))  # (B, D1)
+        with span("serve.commit.verify", ob, t, slots=len(spec)):
             commit = np.zeros(self.batch, np.int32)
             emitted: dict[int, list[int]] = {}
             for s in spec:
@@ -814,9 +880,12 @@ class Server:
                 req.spec_accepted += k
                 self.spec_proposed += len(dr)
                 self.spec_accepted += k
-            _, self.state = self._prefill_step(
-                self.params, self.state, jnp.asarray(tokens),
-                _snapshot(self.slot_pos), jnp.asarray(commit), *extra)
+        with span("serve.pack.accept", ob, t):
+            pos, lens = _snapshot(self.slot_pos), jnp.asarray(commit)
+        with span("serve.dispatch.accept", ob, t):
+            _, self.state = self._prefill_step(self.params, self.state, tok,
+                                               pos, lens, *extra)
+        with span("serve.commit.accept", ob, t, slots=len(spec)):
             self.spec_ticks += 1
             for s in spec:
                 req = self.slot_req[s]
@@ -830,13 +899,11 @@ class Server:
                     # the table must match a never-speculated drain
                     self.alloc.rewind(s, int(self.slot_pos[s]))
                 self._retire_if_done(s)
-            if ob is not None:
-                ob.phase_end("speculate", self.ticks, t0,
-                             sync=self.state, slots=len(spec))
+        return table
 
-        if prefill:
-            if ob is not None:
-                t0 = ob.phase_begin("prefill", self.ticks)
+    def _run_prefill(self, prefill: list[int], table) -> None:
+        ob, t = self.obs, self.ticks
+        with span("serve.pack.prefill", ob, t):
             T = self.prefill_chunk
             tokens = np.zeros((self.batch, T), np.int32)
             lengths = np.zeros(self.batch, np.int32)
@@ -849,11 +916,16 @@ class Server:
                 n = min(T, req._prefill_target - cur)
                 tokens[s, :n] = stream[cur:cur + n]
                 lengths[s] = n
-            extra = (page_table,) if self.paged else ()
+            table = self._table(table)
+            extra = (table,) if self.paged else ()
+            tok, pos = jnp.asarray(tokens), _snapshot(self.slot_pos)
+            lens = jnp.asarray(lengths)
+        with span("serve.dispatch.prefill", ob, t):
             logits, self.state = self._prefill_step(
-                self.params, self.state, jnp.asarray(tokens),
-                _snapshot(self.slot_pos), jnp.asarray(lengths), *extra)
+                self.params, self.state, tok, pos, lens, *extra)
+        with span("serve.readback.prefill", ob, t):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("serve.commit.prefill", ob, t, slots=len(prefill)):
             self.prefill_chunks += len(prefill)
             for s in prefill:
                 req = self.slot_req[s]
@@ -864,22 +936,6 @@ class Server:
                     req.out.append(int(nxt[s]))
                     self.tokens_generated += 1
                     self._retire_if_done(s)
-            if ob is not None:
-                ob.phase_end("prefill", self.ticks, t0, sync=self.state,
-                             slots=len(prefill))
-
-        # sliding-window reclamation: pages whose positions all fell out
-        # of the window are never attended again — hand them back.  The
-        # next tick's earliest attended position is slot_pos - window + 1.
-        if self.paged and self.api.cfg.window is not None:
-            w = self.api.cfg.window
-            for s in range(self.batch):
-                if self.slot_req[s] is not None:
-                    self.alloc.trim(s, max(0, int(self.slot_pos[s]) - w + 1))
-        if ob is not None:
-            ob.on_tick_end(self, self.ticks, n_decode=len(decode),
-                           n_spec=len(spec), n_prefill=len(prefill))
-        return len(active)
 
     def run_until_drained(self, max_ticks: int = 10_000) -> None:
         for _ in range(max_ticks):
