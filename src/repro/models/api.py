@@ -83,9 +83,10 @@ class ModelAPI:
 
         ``paged`` (a :class:`~repro.runtime.kv.PagedKVSpec`) swaps the
         per-slot KV rings for a shared page pool — attention state
-        becomes ``(n_pages, Hkv, page_size, hd)`` and slots address it
-        through the page table fed to :meth:`decode_step` /
-        :meth:`prefill_step`.  Recurrent (SSM) and cross/encoder state
+        becomes ``n_pages`` pages, each a flat row-major
+        ``(Hkv, page_size, hd)`` block (:func:`attn.kv_pool_specs`), and
+        slots address it through the page table fed to
+        :meth:`decode_step` / :meth:`prefill_step`.  Recurrent (SSM) and cross/encoder state
         stay per-slot: they are O(1) in context, paging buys nothing.
         ``dtype`` overrides the KV storage dtype (default bfloat16) —
         pass the params' dtype to keep a float32 model float32 through
@@ -156,7 +157,15 @@ class ModelAPI:
         attention state to the paged pool layout; ``active`` ((B,)
         bool) then gates pool writes per slot INSIDE attention — the
         pool is shared, so the caller cannot slice a per-slot merge out
-        of the returned state the way it can with per-slot rings."""
+        of the returned state the way it can with per-slot rings.  The
+        stacked ``(L, P, R, W)`` pool rides through the layer scan as
+        part of the carry, next to the hidden state: each layer writes
+        the B new rows by index into the pages they land in (an
+        inactive slot's page index is out of range and dropped) and
+        gathers its pages from it, so the pool is never re-stacked as
+        a scan output.  A caller that donates ``state`` at its jit
+        boundary (the paged ``Server`` does) then has XLA update the
+        pool in place."""
 
         cfg = self.cfg
         kinds, _ = _block_plan(cfg)
@@ -167,18 +176,9 @@ class ModelAPI:
             x = x + _sinusoid_at(cur_len[:, None], cfg.d_model, x.dtype)
 
         body = make_decode_body(cfg, kinds, cur_len, page_table, active)
-
-        if cfg.is_encdec:
-            xs = (params["blocks"], state["blocks"],
-                  params["xattn_blocks"], state["xattn"])
-        else:
-            xs = (params["blocks"], state["blocks"])
-        with jax.named_scope("layers"):
-            x, new_blocks = jax.lax.scan(body, x, xs)
-        logits = _logits(params, cfg, x)[:, 0]
-        new_state = dict(state)
-        new_state["blocks"] = new_blocks
-        return logits, new_state
+        x, new_state = _scan_layers(cfg, body, params, state, x,
+                                    page_table is not None)
+        return _logits(params, cfg, x)[:, 0], new_state
 
     def prefill_step(self, params, state, tokens: jax.Array,
                      positions: jax.Array, lengths: jax.Array | None = None,
@@ -252,16 +252,8 @@ class ModelAPI:
 
         body = make_prefill_body(cfg, kinds, positions, lengths, valid,
                                  page_table)
-
-        if cfg.is_encdec:
-            xs = (params["blocks"], state["blocks"],
-                  params["xattn_blocks"], state["xattn"])
-        else:
-            xs = (params["blocks"], state["blocks"])
-        with jax.named_scope("layers"):
-            x, new_blocks = jax.lax.scan(body, x, xs)
-        new_state = dict(state)
-        new_state["blocks"] = new_blocks
+        x, new_state = _scan_layers(cfg, body, params, state, x,
+                                    page_table is not None)
         return x, new_state, lengths
 
     def encode_cross_kv(self, params, frames: jax.Array) -> dict:
@@ -340,51 +332,94 @@ class ModelAPI:
                 **paged_specs}
 
 
-def make_decode_body(cfg: ArchConfig, kinds: list[str], cur_len: jax.Array,
-                     page_table: jax.Array | None = None,
-                     active: jax.Array | None = None):
-    """One decode block: the scan body of ``decode_step`` and the unit
-    lowered by the dry-run's block-cost analysis.  With ``page_table``
-    the attention state is the shared paged pool (``active`` gates its
-    writes per slot); recurrent/cross state is per-slot either way."""
+def _scan_layers(cfg: ArchConfig, body, params, state, x, paged: bool):
+    """The layer scan of ``decode_step`` and ``_chunk_forward``; returns
+    ``(hidden, new state)``.
 
-    def one_attn(p, hn, c):
-        if page_table is not None:
-            return attn.decode_attention_paged(
-                p, cfg, hn, c, page_table, cur_len, window=cfg.window,
-                active=active)
-        return attn.decode_attention(p, cfg, hn, c, cur_len,
-                                     window=cfg.window)
+    Per-slot state (KV rings, SSM, cross K/V) is scanned in lock-step
+    with the stacked block params and stacked again as the scan's
+    output.  Paged, every block's ``"kv"`` pool — already stacked
+    ``(L, P, R, W)`` — leaves ``xs``/``ys`` and rides in the carry next
+    to ``h``, with the layer index scanned as ``arange(L)``: each layer
+    writes its pages of the carried pool, and no scan output re-stacks
+    it."""
+
+    cross = ((params["xattn_blocks"], state["xattn"]) if cfg.is_encdec
+             else ())
+    with jax.named_scope("layers"):
+        if not paged:
+            x, blocks = jax.lax.scan(
+                body, x, (params["blocks"], state["blocks"], *cross))
+        else:
+            pool, rest = _split_pool(state["blocks"])
+            n = jax.tree.leaves(params["blocks"])[0].shape[0]
+            (x, pool), rest = jax.lax.scan(
+                body, (x, pool),
+                (jnp.arange(n), params["blocks"], rest, *cross))
+            blocks = _join_pool(pool, rest)
+    return x, {**state, "blocks": blocks}
+
+
+def _split_pool(blocks: dict) -> tuple[dict, dict]:
+    """Stacked decode state -> (each block's paged ``"kv"`` pool, the
+    per-slot rest)."""
+
+    pool = {key: e["kv"] for key, e in blocks.items() if "kv" in e}
+    rest = {key: {n: v for n, v in e.items() if n != "kv"}
+            for key, e in blocks.items()}
+    return pool, rest
+
+
+def _join_pool(pool: dict, rest: dict) -> dict:
+    """Inverse of :func:`_split_pool`."""
+
+    return {key: {**e, "kv": pool[key]} if key in pool else e
+            for key, e in rest.items()}
+
+
+def _make_body(cfg: ArchConfig, kinds: list[str], one_attn, one_ssm,
+               paged: bool):
+    """One block over all its kinds — the scan body shared by decode and
+    chunked prefill, which differ only in ``one_attn(p, hn, kv, layer)``
+    and ``one_ssm(p, hn, state)``.
+
+    Contiguous: ``body(h, (bp, cache[, xp, xkv])) -> (h, new_cache)``.
+    Paged: ``body((h, pool), (layer, bp, cache[, xp, xkv])) -> ((h,
+    pool), new_cache)``, the stacked pool carried (see
+    :func:`_scan_layers`) and ``cache`` without its ``"kv"``."""
 
     def body(carry, scanned):
-        h = carry
-        if cfg.is_encdec:
-            bp, cache, xp, xkv = scanned
+        if paged:
+            (h, pool), (layer, bp, cache, *cross) = carry, scanned
         else:
-            bp, cache = scanned
+            h, pool, layer = carry, None, None
+            bp, cache, *cross = scanned
         new_cache = {}
         for i, kind in enumerate(kinds):
             key = f"{i}_{kind}"
             p, c = bp[key], cache[key]
             nc: dict[str, Any] = {}
             hn = rms_norm(h, p["ln1"])
-            if kind in ("dense", "moe", "encoder"):
-                a, nc["kv"] = one_attn(p["attn"], hn, c["kv"])
-                h = h + a
-            elif kind == "hybrid":
-                a, nc["kv"] = one_attn(p["attn"], hn, c["kv"])
-                m, nc["ssm"] = ssm_mod.ssm_decode_step(
-                    p["ssm"], cfg, hn, c["ssm"])
+            if kind in ("dense", "moe", "encoder", "hybrid"):
+                a, kv = one_attn(p["attn"], hn,
+                                 pool[key] if paged else c["kv"], layer)
+                if paged:
+                    pool = {**pool, key: kv}
+                else:
+                    nc["kv"] = kv
+            if kind in ("ssm", "hybrid"):
+                m, nc["ssm"] = one_ssm(p["ssm"], hn, c["ssm"])
+            if kind == "hybrid":
                 h = h + p["mix"][0] * a + p["mix"][1] * m
             elif kind == "ssm":
-                m, nc["ssm"] = ssm_mod.ssm_decode_step(
-                    p["ssm"], cfg, hn, c["ssm"])
                 h = h + m
             elif kind == "cross":
                 a = attn.decode_cross_attention(p["xattn"], cfg, hn,
                                                 c["enc_kv"])
                 h = h + jnp.tanh(p["gate"]).astype(h.dtype) * a
                 nc["enc_kv"] = c["enc_kv"]
+            else:
+                h = h + a
             if "ffn" in p:
                 h2 = rms_norm(h, p["ln2"])
                 if kind == "moe":
@@ -393,12 +428,36 @@ def make_decode_body(cfg: ArchConfig, kinds: list[str], cur_len: jax.Array,
                     h = h + mlp_forward(p["ffn"], cfg, h2)
             new_cache[key] = nc
         if cfg.is_encdec:
+            xp, xkv = cross
             a = attn.decode_cross_attention(
                 xp["x"], cfg, rms_norm(h, xp["ln_x"]), xkv)
             h = h + a
-        return h, new_cache
+        return ((h, pool) if paged else h), new_cache
 
     return body
+
+
+def make_decode_body(cfg: ArchConfig, kinds: list[str], cur_len: jax.Array,
+                     page_table: jax.Array | None = None,
+                     active: jax.Array | None = None):
+    """One decode block: the scan body of ``decode_step`` and the unit
+    lowered by the dry-run's block-cost analysis.  With ``page_table``
+    the attention state is the shared paged pool, carried (``active``
+    gates its writes per slot); recurrent/cross state is per-slot
+    either way.  Signatures in :func:`_make_body`."""
+
+    def one_attn(p, hn, kv, layer):
+        if page_table is not None:
+            return attn.decode_attention_paged(
+                p, cfg, hn, kv, page_table, cur_len, layer=layer,
+                window=cfg.window, active=active)
+        return attn.decode_attention(p, cfg, hn, kv, cur_len,
+                                     window=cfg.window)
+
+    def one_ssm(p, hn, st):
+        return ssm_mod.ssm_decode_step(p, cfg, hn, st)
+
+    return _make_body(cfg, kinds, one_attn, one_ssm, page_table is not None)
 
 
 def make_prefill_body(cfg: ArchConfig, kinds: list[str],
@@ -413,57 +472,18 @@ def make_prefill_body(cfg: ArchConfig, kinds: list[str],
     chunk via scan, the enc-dec cross path is unchanged (already
     chunk-shape agnostic)."""
 
-    def one_attn(p, hn, c):
+    def one_attn(p, hn, kv, layer):
         if page_table is not None:
             return attn.decode_attention_chunked_paged(
-                p, cfg, hn, c, page_table, positions, lengths,
-                window=cfg.window)
+                p, cfg, hn, kv, page_table, positions, lengths,
+                layer=layer, window=cfg.window)
         return attn.decode_attention_chunked(
-            p, cfg, hn, c, positions, lengths, window=cfg.window)
+            p, cfg, hn, kv, positions, lengths, window=cfg.window)
 
-    def body(carry, scanned):
-        h = carry
-        if cfg.is_encdec:
-            bp, cache, xp, xkv = scanned
-        else:
-            bp, cache = scanned
-        new_cache = {}
-        for i, kind in enumerate(kinds):
-            key = f"{i}_{kind}"
-            p, c = bp[key], cache[key]
-            nc: dict[str, Any] = {}
-            hn = rms_norm(h, p["ln1"])
-            if kind in ("dense", "moe", "encoder"):
-                a, nc["kv"] = one_attn(p["attn"], hn, c["kv"])
-                h = h + a
-            elif kind == "hybrid":
-                a, nc["kv"] = one_attn(p["attn"], hn, c["kv"])
-                m, nc["ssm"] = ssm_mod.ssm_prefill_step(
-                    p["ssm"], cfg, hn, c["ssm"], valid)
-                h = h + p["mix"][0] * a + p["mix"][1] * m
-            elif kind == "ssm":
-                m, nc["ssm"] = ssm_mod.ssm_prefill_step(
-                    p["ssm"], cfg, hn, c["ssm"], valid)
-                h = h + m
-            elif kind == "cross":
-                a = attn.decode_cross_attention(p["xattn"], cfg, hn,
-                                                c["enc_kv"])
-                h = h + jnp.tanh(p["gate"]).astype(h.dtype) * a
-                nc["enc_kv"] = c["enc_kv"]
-            if "ffn" in p:
-                h2 = rms_norm(h, p["ln2"])
-                if kind == "moe":
-                    h = h + moe_mod.moe_forward(p["ffn"], cfg, h2)
-                else:
-                    h = h + mlp_forward(p["ffn"], cfg, h2)
-            new_cache[key] = nc
-        if cfg.is_encdec:
-            a = attn.decode_cross_attention(
-                xp["x"], cfg, rms_norm(h, xp["ln_x"]), xkv)
-            h = h + a
-        return h, new_cache
+    def one_ssm(p, hn, st):
+        return ssm_mod.ssm_prefill_step(p, cfg, hn, st, valid)
 
-    return body
+    return _make_body(cfg, kinds, one_attn, one_ssm, page_table is not None)
 
 
 def _sinusoid_at(pos: jax.Array, d: int, dtype) -> jax.Array:

@@ -13,6 +13,7 @@ tileable call runs the compiled kernel.
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -364,30 +365,97 @@ def kv_pool_specs(cfg: ArchConfig, n_pages: int, page_size: int,
     pages shared by every serving slot, addressed through per-slot page
     tables (:mod:`repro.runtime.kv`) — the paged sibling of
     :func:`kv_cache_specs`.  Pages play the batch role of the
-    contiguous layout, so they take its sharding axis.  ``dtype`` as in
+    contiguous layout, so they take its sharding axis.
+
+    A page's ``(Hkv, page_size, hd)`` block is stored flat, row-major,
+    as ``(R, W)`` rows of ``W = gcd(Hkv * page_size * hd, 128)``
+    elements: rows as wide as the TPU's 128 lanes, whatever the head
+    size.  The chip then keeps the pool row-major and reads and writes
+    whole pages by index; with the head size as the minor dim a 64-wide
+    head is laid out with the page index minor, and every page access
+    relayouts the whole pool.  The decode state stacks the pool over
+    layers; the paged attention paths take that stacked ``(L, P, R,
+    W)`` pool and a layer index.  ``dtype`` as in
     :func:`kv_cache_specs`."""
 
     Hkv, hd = cfg.n_kv_heads, cfg.hd
     dt = dtype if dtype is not None else DEFAULT_DTYPE
-    return {
-        "k": PSpec((n_pages, Hkv, page_size, hd),
-                   ("cache_batch", "kv_heads", None, "head_dim"),
-                   init="zeros", dtype=dt),
-        "v": PSpec((n_pages, Hkv, page_size, hd),
-                   ("cache_batch", "kv_heads", None, "head_dim"),
-                   init="zeros", dtype=dt),
-    }
+    E = Hkv * page_size * hd
+    W = math.gcd(E, 128)
+    return {n: PSpec((n_pages, E // W, W),
+                     ("cache_batch", None, None), init="zeros", dtype=dt)
+            for n in ("k", "v")}
 
 
-def _gather_pool(pool_kv: jax.Array, page_table: jax.Array) -> jax.Array:
-    """(P, Hkv, ps, hd) pool -> (B, Hkv, M*ps, hd) per-slot linear view
-    through the page table (unallocated entries gather page 0 — callers
-    mask them by ``page_table >= 0``)."""
+def _pool_page_size(pool_kv: jax.Array, Hkv: int, hd: int) -> int:
+    """Tokens a page of the stacked (L, P, R, W) pool holds."""
 
+    return pool_kv.shape[2] * pool_kv.shape[3] // (Hkv * hd)
+
+
+def _gather_pool(pool_kv: jax.Array, layer: jax.Array,
+                 page_table: jax.Array, Hkv: int, hd: int) -> jax.Array:
+    """Layer ``layer`` of the stacked (L, P, R, W) pool -> (B, Hkv, M*ps,
+    hd) per-slot linear view through the page table (unallocated
+    entries gather page 0 — callers mask them by ``page_table >= 0``).
+    One gather indexes layer and page together, so no slice of the
+    layer's whole pool is ever materialised."""
+
+    ps = _pool_page_size(pool_kv, Hkv, hd)
     with jax.named_scope("kv_read"):
-        g = pool_kv[jnp.clip(page_table, 0)]      # (B, M, Hkv, ps, hd)
-        B, M, Hkv, ps, hd = g.shape
-        return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * ps, hd)
+        g = pool_kv[layer, jnp.clip(page_table, 0)]   # (B, M, R, W)
+        B, M = page_table.shape
+        return g.reshape(B, M, Hkv, ps, hd).transpose(
+            0, 2, 1, 3, 4).reshape(B, Hkv, M * ps, hd)
+
+
+def _write_pool(pool: dict, layer: jax.Array, page_table: jax.Array,
+                cur_len: jax.Array, lengths: jax.Array, k_new: jax.Array,
+                v_new: jax.Array) -> dict:
+    """Write each slot's first ``lengths[b]`` new K/V rows (k_new/v_new:
+    (B, Hkv, T, hd), token ``t`` at position ``cur_len[b] + t``) into
+    layer ``layer`` of the stacked pool, touching only their pages.
+
+    A run of T tokens spans at most ``nP = (T + 2*ps - 2) // ps``
+    pages.  Those pages are gathered by index, the new rows merged in
+    over the old ones (token ``t`` lands at row ``cur_len % ps + t`` of
+    the pages laid end to end), and the pages scattered back whole —
+    full-row windows, which the TPU scatters natively into the
+    row-major pool.  A page no valid row lands in (inactive slot,
+    ``t >= lengths`` padding, unbacked or past the table) gets the
+    out-of-range index ``P`` and ``mode="drop"`` discards it; a ``-1``
+    would wrap to the last page under NumPy indexing.  Pages belong to
+    one slot (allocator invariant), so no two writes hit one page.
+    With the pool carried through the layer scan and donated at the
+    jit boundary, XLA updates it in place: the bytes written scale
+    with B·T, not with the pool."""
+
+    B, Hkv, T, hd = k_new.shape
+    P, R, W = pool["k"].shape[1:]
+    ps = _pool_page_size(pool["k"], Hkv, hd)
+    M = page_table.shape[1]
+    nP = (T + 2 * ps - 2) // ps
+    first = cur_len % ps                                # (B,)
+    j = jnp.arange(nP, dtype=jnp.int32)
+    lp = cur_len[:, None] // ps + j                     # (B, nP) logical
+    touched = (lengths[:, None] > 0) & (j * ps < (first + lengths)[:, None]) \
+        & (lp < M)
+    phys = jnp.take_along_axis(page_table, jnp.minimum(lp, M - 1), axis=1)
+    phys = jnp.where(touched & (phys >= 0), phys, P)    # (B, nP)
+    t = jnp.arange(nP * ps, dtype=jnp.int32)[None, :] - first[:, None]
+    fresh = ((t >= 0) & (t < lengths[:, None])).reshape(B, nP, 1, ps, 1)
+    src = jnp.clip(t, 0, T - 1)[:, None, :, None]       # (B, 1, nP*ps, 1)
+
+    def put(pool_n, new):
+        rows = jnp.take_along_axis(new, src, axis=2)    # (B,Hkv,nP*ps,hd)
+        rows = rows.reshape(B, Hkv, nP, ps, hd).transpose(0, 2, 1, 3, 4)
+        old = pool_n[layer, jnp.minimum(phys, P - 1)].reshape(
+            B, nP, Hkv, ps, hd)
+        pages = jnp.where(fresh, rows.astype(pool_n.dtype), old)
+        return pool_n.at[layer, phys].set(pages.reshape(B, nP, R, W),
+                                          mode="drop")
+
+    return {"k": put(pool["k"], k_new), "v": put(pool["v"], v_new)}
 
 
 def _pool_validity(page_table: jax.Array, page_size: int) -> jax.Array:
@@ -419,26 +487,33 @@ def _grouped_sdpa(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def decode_attention_paged(p: dict, cfg: ArchConfig, x: jax.Array,
                            pool: dict, page_table: jax.Array,
-                           cur_len: jax.Array, *,
+                           cur_len: jax.Array, *, layer: jax.Array,
                            window: int | None = None,
                            active: jax.Array | None = None
                            ) -> tuple[jax.Array, dict]:
     """One-token attention against a PAGED KV pool — the paged sibling
     of :func:`decode_attention`, same numerics.
 
-    x: (B, 1, d); pool["k"/"v"]: (P, Hkv, page_size, hd) shared by all
-    slots; page_table: (B, M) physical page per logical page (-1 =
-    unallocated); cur_len: (B,) tokens already cached per slot.  The
-    new K/V lands at physical page ``page_table[b, pos // ps]``, offset
-    ``pos % ps``; queries then attend the slot's pages through a
-    page-table gather under the same absolute-position causal/window
-    masks as the contiguous path.  ``active`` gates writes per slot —
-    the pool is SHARED, so the server cannot gate the merged state
-    per-slot afterwards the way it can with per-slot rings; an idle or
-    prefilling neighbour must not scatter a garbage token here."""
+    x: (B, 1, d); pool["k"/"v"]: (L, P, R, W), every layer's pages
+    (:func:`kv_pool_specs`) stacked, shared by all slots; ``layer``: this layer's index into it; page_table: (B, M)
+    physical page per logical page (-1 = unallocated); cur_len: (B,)
+    tokens already cached per slot.
+
+    The new K/V row lands at offset ``pos % ps`` of physical page
+    ``page_table[b, pos // ps]``, written by index (:func:`_write_pool`):
+    B pages are touched, not the pool.  ``active`` gates the write per
+    slot — the pool is SHARED, so the server cannot gate the merged
+    state per slot afterwards the way it can with per-slot rings — and
+    an inactive slot's page index is out of range, so the scatter drops
+    it.  Queries then attend the slot's pages through a page-table
+    gather of the updated pool under the same absolute-position
+    causal/window masks as the contiguous path.  Returns the stacked
+    pool, to be carried through the layer scan (see
+    :meth:`ModelAPI.decode_step`)."""
 
     B, one, d = x.shape
-    P, Hkv, ps, hd = pool["k"].shape
+    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    ps = _pool_page_size(pool["k"], Hkv, hd)
     M = page_table.shape[1]
     cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (B,))
     if active is None:
@@ -450,33 +525,15 @@ def decode_attention_paged(p: dict, cfg: ArchConfig, x: jax.Array,
             q = rope(q, positions, cfg.rope_theta)
             k_new = rope(k_new, positions, cfg.rope_theta)
 
-    # scatter: slot b writes page page_table[b, pos//ps] offset pos%ps.
-    # Pages are slot-exclusive (allocator invariant), so at most one
-    # slot writes any (page, offset); one-hot + argmax keeps the update
-    # elementwise under pool sharding, as in decode_attention.
     with jax.named_scope("kv_write"):
-        lp = jnp.clip(cur_len // ps, 0, M - 1)    # (B,) logical page
-        off = cur_len % ps                        # (B,)
-        phys = jnp.take_along_axis(page_table, lp[:, None], axis=1)[:, 0]
-        phys = jnp.where(active, phys, -1)        # inactive: never match
-        hot = (jnp.arange(P)[None, :] == phys[:, None])[:, :, None] \
-            & (jnp.arange(ps)[None, :] == off[:, None])[:, None, :]
-        written = hot.any(axis=0)                 # (P, ps)
-        writer = jnp.argmax(hot, axis=0)          # (P, ps) -> slot index
-
-        def scatter(new, old):                    # new: (B, Hkv, 1, hd)
-            vals = new[writer, :, 0, :].transpose(0, 2, 1, 3)
-            return jnp.where(written[:, None, :, None],
-                             vals.astype(old.dtype), old)
-
-        new_pool = {"k": scatter(k_new, pool["k"]),
-                    "v": scatter(v_new, pool["v"])}
+        new_pool = _write_pool(pool, layer, page_table, cur_len,
+                               active.astype(jnp.int32), k_new, v_new)
 
     # gather the slot's linear view from the UPDATED pool; position t
     # lives at page t//ps — validity is the same absolute-position mask
     # as the contiguous path plus "is the page live"
-    k = _gather_pool(new_pool["k"], page_table)   # (B, Hkv, M*ps, hd)
-    v = _gather_pool(new_pool["v"], page_table)
+    k = _gather_pool(new_pool["k"], layer, page_table, Hkv, hd)
+    v = _gather_pool(new_pool["v"], layer, page_table, Hkv, hd)
     with jax.named_scope("attend"):
         t = jnp.arange(M * ps, dtype=jnp.int32)[None, :]    # (1, M*ps)
         cl = cur_len[:, None]                               # (B, 1)
@@ -493,26 +550,30 @@ def decode_attention_paged(p: dict, cfg: ArchConfig, x: jax.Array,
 def decode_attention_chunked_paged(p: dict, cfg: ArchConfig, x: jax.Array,
                                    pool: dict, page_table: jax.Array,
                                    cur_len: jax.Array, lengths: jax.Array,
-                                   *, window: int | None = None
+                                   *, layer: jax.Array,
+                                   window: int | None = None
                                    ) -> tuple[jax.Array, dict]:
     """Chunked cached prefill against a paged pool — the paged sibling
-    of :func:`decode_attention_chunked`.
+    of :func:`decode_attention_chunked`.  ``pool`` and ``layer`` as in
+    :func:`decode_attention_paged`.
 
     Unlike the ring layout, paged positions are unique (no wraparound
-    inside a chunk), so the chunk K/V is scattered first and queries
+    inside a chunk), so the chunk K/V is written first and queries
     attend the *updated* pool directly: every key position ``<= qp`` is
-    genuinely written, the chunk-causal mask does the rest.  ``lengths``
-    gates both the scatter and nothing else is needed per slot —
-    padding rows (idle/decoding neighbours) write no page."""
+    genuinely written, the chunk-causal mask does the rest.  The write
+    (:func:`_write_pool`) touches only the pages the chunk's valid rows
+    land in; rows at ``t >= lengths`` (padding of idle/decoding
+    neighbours) are masked out of them, and a page no valid row reaches
+    goes to an out-of-range index that the scatter drops."""
 
     B, T, d = x.shape
-    P, Hkv, ps, hd = pool["k"].shape
-    M = page_table.shape[1]
+    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    ps = _pool_page_size(pool["k"], Hkv, hd)
     cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (B,))
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     t_idx = jnp.arange(T, dtype=jnp.int32)
     pos = cur_len[:, None] + t_idx[None, :]            # (B, T) absolute
-    valid = t_idx[None, :] < lengths[:, None]          # (B, T)
+    M = page_table.shape[1]
 
     with jax.named_scope("qkv"):
         q, k_new, v_new = _project_qkv(p, cfg, x, x)
@@ -520,36 +581,16 @@ def decode_attention_chunked_paged(p: dict, cfg: ArchConfig, x: jax.Array,
             q = rope(q, pos, cfg.rope_theta)
             k_new = rope(k_new, pos, cfg.rope_theta)
 
-    # scatter: chunk token (b, t) -> page page_table[b, pos//ps], offset
-    # pos%ps; valid tokens only.  Positions are unique and pages
-    # slot-exclusive, so at most one (b, t) writes any (page, offset).
     with jax.named_scope("kv_write"):
-        lp = jnp.clip(pos // ps, 0, M - 1)             # (B, T)
-        off = pos % ps
-        phys = jnp.take_along_axis(page_table, lp, axis=1)  # (B, T)
-        phys = jnp.where(valid, phys, -1)
-        hot = (phys[:, :, None] == jnp.arange(P)[None, None, :])[..., None] \
-            & (off[:, :, None]
-               == jnp.arange(ps)[None, None, :])[:, :, None, :]
-        hot = hot.reshape(B * T, P, ps)                # (B*T, P, ps)
-        written = hot.any(axis=0)                      # (P, ps)
-        writer = jnp.argmax(hot, axis=0)               # (P, ps) -> b*T+t
-
-        def scatter(new, old):                         # new: (B,Hkv,T,hd)
-            flat = new.transpose(0, 2, 1, 3).reshape(B * T, Hkv, hd)
-            vals = flat[writer].transpose(0, 2, 1, 3)  # (P, Hkv, ps, hd)
-            return jnp.where(written[:, None, :, None],
-                             vals.astype(old.dtype), old)
-
-        new_pool = {"k": scatter(k_new, pool["k"]),
-                    "v": scatter(v_new, pool["v"])}
+        new_pool = _write_pool(pool, layer, page_table, cur_len, lengths,
+                               k_new, v_new)
 
     # chunk-causal read over the updated pool: key position t is valid
     # for query position qp when t <= qp (all such positions are
     # written — this request's earlier ticks or this chunk) and its
     # page is live
-    k = _gather_pool(new_pool["k"], page_table)        # (B, Hkv, M*ps, hd)
-    v = _gather_pool(new_pool["v"], page_table)
+    k = _gather_pool(new_pool["k"], layer, page_table, Hkv, hd)
+    v = _gather_pool(new_pool["v"], layer, page_table, Hkv, hd)
     with jax.named_scope("attend"):
         kp = jnp.arange(M * ps, dtype=jnp.int32)[None, None, :]
         qp = pos[:, :, None]                           # (B, T, 1)

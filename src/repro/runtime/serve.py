@@ -117,6 +117,114 @@ def _snapshot(a: np.ndarray) -> jax.Array:
     return jnp.asarray(np.array(a))
 
 
+def server_steps(api: ModelAPI, paged: bool) -> tuple:
+    """The jitted ``(decode, prefill, verify)`` steps a ``Server`` of
+    ``api`` dispatches, built once per ``(api, paged)`` and cached on
+    ``api``.  Their arguments: ``(params, state, tokens, positions,
+    active | lengths[, page_table])``; each returns ``(logits, new
+    state)``."""
+
+    # jitted one-token step over the whole slot batch; positions is
+    # the (batch,) per-slot position vector — decode_step threads it
+    # through RoPE, the ring-cache slot, and the validity mask, so
+    # mixed-progress slots coexist correctly in one batch.  ``active``
+    # gates the state merge per slot: slots mid-prefill (or idle)
+    # must not have a garbage token scattered into their KV ring or
+    # folded into their SSM recurrence.
+    def gate(active, new, old):
+        # per-slot leaves are (L, batch, ...): keep old rows of
+        # inactive slots
+        return jax.tree.map(
+            lambda n, o: jnp.where(active.reshape(
+                (1, active.shape[0]) + (1,) * (n.ndim - 2)), n, o),
+            new, old)
+
+    def step(params, state, tokens, positions, active):
+        logits, new_state = api.decode_step(params, state, tokens,
+                                            positions)
+        return logits, gate(active, new_state, state)
+
+    # paged sibling: the KV pool is SHARED, so its writes are gated
+    # per slot inside the paged attention (``active``); only the
+    # per-slot leaves (SSM recurrence, cross K/V) are merge-gated
+    # here — a blanket tree-map of ``sel`` would slice the pool on
+    # its page dim as if it were a slot dim
+    def step_paged(params, state, tokens, positions, active,
+                   page_table):
+        logits, new_state = api.decode_step(params, state, tokens,
+                                            positions, page_table,
+                                            active)
+        blocks = {}
+        for key, entry in new_state["blocks"].items():
+            old = state["blocks"][key]
+            blocks[key] = {
+                k2: (v2 if k2 == "kv" else gate(active, v2, old[k2]))
+                for k2, v2 in entry.items()}
+        return logits, {**new_state, "blocks": blocks}
+
+    # jitted chunked-prefill step: per-slot chunk lengths gate every
+    # state write inside the model (KV scatter, SSM scan, paged
+    # pool), so one static-shape call serves any mix of
+    # prefilling/other slots
+    def pstep(params, state, tokens, positions, lengths):
+        return api.prefill_step(params, state, tokens, positions,
+                                lengths)
+
+    def pstep_paged(params, state, tokens, positions, lengths,
+                    page_table):
+        return api.prefill_step(params, state, tokens, positions,
+                                lengths, page_table)
+
+    # speculation verifier: one chunk forward scoring all depth+1
+    # candidate positions.  Its returned STATE is always discarded
+    # (it holds rejected tokens' cache writes); the accepted prefix
+    # is committed by a second, length-gated ``_prefill_step`` call
+    # — the only uniform way to keep SSM/hybrid recurrence exact
+    # under partial acceptance.
+    def vstep(params, state, tokens, positions, lengths):
+        return api.verify_step(params, state, tokens, positions,
+                               lengths)
+
+    def vstep_paged(params, state, tokens, positions, lengths,
+                    page_table):
+        return api.verify_step(params, state, tokens, positions,
+                               lengths, page_table)
+
+    # The jitted steps are built once per (api, paged) and SHARED by
+    # every Server in the process (cached on the api object).  This
+    # is a correctness requirement, not a compile-time nicety:
+    # XLA:CPU native codegen is not bit-reproducible across separate
+    # compiles of the same HLO — under CPU contention two jax.jit
+    # calls on identical code can yield executables whose float
+    # rounding differs enough to flip a near-tie argmax — so a
+    # speculative server and its plain-decode baseline must argmax
+    # through the SAME compiled step to be token-for-token
+    # comparable.  jax.jit retraces per batch/context/dtype, so one
+    # cache entry serves all server shapes.
+    #
+    # The paged decode and prefill steps DONATE the state (argument
+    # 1): the pool rides through the layer scan's carry and each
+    # step rewrites only the pages its new rows land in, so with the
+    # input buffer donated XLA writes the pool in place instead of
+    # copying it.
+    # ``Server`` rebinds its ``state`` to the step's output at every
+    # such call, so nothing reads a donated buffer.  The verifier
+    # does not donate: its state is discarded and the server's state
+    # feeds the commit prefill right after.
+    cache = getattr(api, "_server_steps", None)
+    if cache is None:
+        cache = {}
+        api._server_steps = cache
+    if paged not in cache:
+        cache[paged] = (
+            jax.jit(step_paged, donate_argnums=1) if paged
+            else jax.jit(step),
+            jax.jit(pstep_paged, donate_argnums=1) if paged
+            else jax.jit(pstep),
+            jax.jit(vstep_paged if paged else vstep))
+    return cache[paged]
+
+
 @dataclass
 class Request:
     rid: int
@@ -211,94 +319,8 @@ class Server:
         self.queue: list[Request] = []
         self.completed: list[Request] = []
 
-        # jitted one-token step over the whole slot batch; positions is
-        # the (batch,) per-slot position vector — decode_step threads it
-        # through RoPE, the ring-cache slot, and the validity mask, so
-        # mixed-progress slots coexist correctly in one batch.  ``active``
-        # gates the state merge per slot: slots mid-prefill (or idle)
-        # must not have a garbage token scattered into their KV ring or
-        # folded into their SSM recurrence.
-        def step(params, state, tokens, positions, active):
-            logits, new_state = api.decode_step(params, state, tokens,
-                                                positions)
-            def sel(new, old):
-                m = active.reshape((1, active.shape[0])
-                                   + (1,) * (new.ndim - 2))
-                return jnp.where(m, new, old)
-            return logits, jax.tree.map(sel, new_state, state)
-
-        # paged sibling: the KV pool is SHARED, so its writes are gated
-        # per slot inside the paged attention (``active``); only the
-        # per-slot leaves (SSM recurrence, cross K/V) are merge-gated
-        # here — a blanket tree-map of ``sel`` would slice the pool on
-        # its page dim as if it were a slot dim
-        def step_paged(params, state, tokens, positions, active,
-                       page_table):
-            logits, new_state = api.decode_step(params, state, tokens,
-                                                positions, page_table,
-                                                active)
-            def sel(new, old):
-                m = active.reshape((1, active.shape[0])
-                                   + (1,) * (new.ndim - 2))
-                return jnp.where(m, new, old)
-            blocks = {}
-            for key, entry in new_state["blocks"].items():
-                old = state["blocks"][key]
-                blocks[key] = {
-                    k2: (v2 if k2 == "kv"
-                         else jax.tree.map(sel, v2, old[k2]))
-                    for k2, v2 in entry.items()}
-            return logits, {**new_state, "blocks": blocks}
-
-        # jitted chunked-prefill step: per-slot chunk lengths gate every
-        # state write inside the model (KV scatter, SSM scan, paged
-        # pool), so one static-shape call serves any mix of
-        # prefilling/other slots
-        def pstep(params, state, tokens, positions, lengths):
-            return api.prefill_step(params, state, tokens, positions,
-                                    lengths)
-
-        def pstep_paged(params, state, tokens, positions, lengths,
-                        page_table):
-            return api.prefill_step(params, state, tokens, positions,
-                                    lengths, page_table)
-
-        # speculation verifier: one chunk forward scoring all depth+1
-        # candidate positions.  Its returned STATE is always discarded
-        # (it holds rejected tokens' cache writes); the accepted prefix
-        # is committed by a second, length-gated ``_prefill_step`` call
-        # — the only uniform way to keep SSM/hybrid recurrence exact
-        # under partial acceptance.
-        def vstep(params, state, tokens, positions, lengths):
-            return api.verify_step(params, state, tokens, positions,
-                                   lengths)
-
-        def vstep_paged(params, state, tokens, positions, lengths,
-                        page_table):
-            return api.verify_step(params, state, tokens, positions,
-                                   lengths, page_table)
-
-        # The jitted steps are built once per (api, paged) and SHARED by
-        # every Server in the process (cached on the api object).  This
-        # is a correctness requirement, not a compile-time nicety:
-        # XLA:CPU native codegen is not bit-reproducible across separate
-        # compiles of the same HLO — under CPU contention two jax.jit
-        # calls on identical code can yield executables whose float
-        # rounding differs enough to flip a near-tie argmax — so a
-        # speculative server and its plain-decode baseline must argmax
-        # through the SAME compiled step to be token-for-token
-        # comparable.  jax.jit retraces per batch/context/dtype, so one
-        # cache entry serves all server shapes.
-        cache = getattr(api, "_server_steps", None)
-        if cache is None:
-            cache = {}
-            api._server_steps = cache
-        if paged not in cache:
-            cache[paged] = (
-                jax.jit(step_paged if paged else step),
-                jax.jit(pstep_paged if paged else pstep),
-                jax.jit(vstep_paged if paged else vstep))
-        self._step, self._prefill_step, self._verify_step = cache[paged]
+        self._step, self._prefill_step, self._verify_step = \
+            server_steps(api, paged)
 
         # observability is strictly additive: every hook below is
         # guarded by `if self.obs is not None` and records host state

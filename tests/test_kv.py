@@ -11,7 +11,8 @@ import pytest
 from repro.configs import get_config
 from repro.models import build_model
 from repro.models.attention import (attn_specs, decode_attention,
-                                    decode_attention_paged)
+                                    decode_attention_chunked_paged,
+                                    decode_attention_paged, kv_pool_specs)
 from repro.models.common import init_params
 from repro.runtime.kv import NO_PAGE, PagedKVAllocator, PagedKVSpec
 from repro.runtime.serve import KVPageTunable, Server
@@ -260,6 +261,29 @@ def test_allocator_stats_reports_shared_pages():
 # ---------------------------------------------------------------------------
 
 
+def _pooled(cfg, pages):
+    """(L, P, Hkv, ps, hd) page blocks -> the stacked (L, P, R, W) pool
+    of :func:`kv_pool_specs` (a row-major reshape)."""
+
+    L, P, Hkv, ps, hd = pages.shape
+    return jnp.asarray(
+        pages.reshape((L,) + kv_pool_specs(cfg, P, ps)["k"].shape))
+
+
+def _paged(pool, Hkv, hd):
+    """Inverse of :func:`_pooled`: (L, P, R, W) -> (L, P, Hkv, ps, hd)."""
+
+    a = np.asarray(pool)
+    L, P, R, W = a.shape
+    return a.reshape(L, P, Hkv, R * W // (Hkv * hd), hd)
+
+
+def _written(before, after):
+    """(layer, page, offset) of every pool row that changed."""
+
+    return np.argwhere((before != after).any(axis=(2, 4))).tolist()
+
+
 def test_decode_attention_paged_matches_contiguous_unit():
     """The paged gather/scatter is pure indirection: with the same K/V
     laid out through an arbitrary (shuffled) page table, one-token
@@ -300,9 +324,9 @@ def test_decode_attention_paged_matches_contiguous_unit():
                                  "v": jnp.asarray(v_cont)},
         jnp.asarray(cur_len))
     out_p, new_p = decode_attention_paged(
-        p, cfg, jnp.asarray(x), {"k": jnp.asarray(pool_k),
-                                 "v": jnp.asarray(pool_v)},
-        jnp.asarray(page_table), jnp.asarray(cur_len))
+        p, cfg, jnp.asarray(x), {"k": _pooled(cfg, pool_k[None]),
+                                 "v": _pooled(cfg, pool_v[None])},
+        jnp.asarray(page_table), jnp.asarray(cur_len), layer=0)
     np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_p),
                                atol=1e-5, rtol=1e-5)
 
@@ -311,7 +335,7 @@ def test_decode_attention_paged_matches_contiguous_unit():
     for b, n in enumerate(cur_len):
         page = page_table[b, n // ps]
         np.testing.assert_allclose(
-            np.asarray(new_p["k"])[page, :, n % ps],
+            _paged(new_p["k"], Hkv, hd)[0, page, :, n % ps],
             np.asarray(new_c["k"])[b, :, n], atol=1e-6)
 
 
@@ -323,14 +347,152 @@ def test_decode_attention_paged_inactive_slots_write_nothing():
         logits_dtype="float32")
     p = init_params(attn_specs(cfg), jax.random.PRNGKey(1))
     Hkv, hd = cfg.n_kv_heads, cfg.hd
-    pool = {"k": jnp.zeros((4, Hkv, 4, hd)), "v": jnp.zeros((4, Hkv, 4, hd))}
+    zeros = np.zeros((1, 4, Hkv, 4, hd), np.float32)
+    pool = {"k": _pooled(cfg, zeros), "v": _pooled(cfg, zeros)}
     page_table = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     x = jnp.ones((2, 1, cfg.d_model))
     _, new_pool = decode_attention_paged(
-        p, cfg, x, pool, page_table, jnp.asarray([0, 0]),
+        p, cfg, x, pool, page_table, jnp.asarray([0, 0]), layer=0,
         active=jnp.asarray([True, False]))
-    assert np.asarray(new_pool["k"])[0].any()          # slot 0 wrote
-    assert not np.asarray(new_pool["k"])[2:].any()     # slot 1 did not
+    k = _paged(new_pool["k"], Hkv, hd)
+    assert k[0, 0].any()                       # slot 0 wrote
+    assert not k[0, 2:].any()                  # slot 1 did not
+
+
+def test_paged_inactive_slot_leaves_every_page_untouched():
+    """A row that must not write is dropped, not wrapped: the inactive
+    slot's table holds the LAST page, where a -1 index would land under
+    NumPy indexing, and an active slot whose write page is unbacked
+    writes nothing either.  Only the one live write changes the pool."""
+
+    cfg = get_config("smollm-135m").reduced().replace(
+        logits_dtype="float32")
+    p = init_params(attn_specs(cfg), jax.random.PRNGKey(1))
+    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    P, ps = 6, 4
+    rng = np.random.default_rng(3)
+    pages = rng.standard_normal((1, P, Hkv, ps, hd)).astype(np.float32)
+    pool = {"k": _pooled(cfg, pages), "v": _pooled(cfg, pages + 1)}
+    # slot 0 inactive; slot 1 active at position ps, logical page 1
+    # unbacked; slot 2 active, writes page 2 at offset 1
+    page_table = jnp.asarray([[P - 1, 0], [1, -1], [2, 3]], jnp.int32)
+    x = jnp.asarray(rng.standard_normal((3, 1, cfg.d_model)), jnp.float32)
+    _, new = decode_attention_paged(
+        p, cfg, x, pool, page_table, jnp.asarray([2, ps, 1]), layer=0,
+        active=jnp.asarray([False, True, True]))
+    for n, before in (("k", pages), ("v", pages + 1)):
+        assert _written(before, _paged(new[n], Hkv, hd)) == [[0, 2, 1]]
+
+
+def test_paged_chunk_rows_past_length_write_nothing():
+    """Chunk rows at ``t >= lengths`` write nothing, even where their
+    page is backed; valid rows on an unbacked page write nothing (and
+    do not wrap to the last page); an idle slot (length 0) whose table
+    holds the last page writes nothing at all."""
+
+    cfg = get_config("smollm-135m").reduced().replace(
+        logits_dtype="float32")
+    p = init_params(attn_specs(cfg), jax.random.PRNGKey(1))
+    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    P, ps, T = 8, 4, 6
+    rng = np.random.default_rng(4)
+    pages = rng.standard_normal((1, P, Hkv, ps, hd)).astype(np.float32)
+    pool = {"k": _pooled(cfg, pages), "v": _pooled(cfg, pages)}
+    page_table = jnp.asarray([[4, 5, 6], [1, -1, 2], [P - 1, 0, 3]],
+                             jnp.int32)
+    x = jnp.asarray(rng.standard_normal((3, T, cfg.d_model)), jnp.float32)
+    # slot 0: positions 3..6 valid (page 4 offset 3, page 5 offsets
+    # 0..2); its rows 4, 5 (positions 7, 8) are padding.  Slot 1:
+    # positions 2..4, the last on its unbacked second page
+    _, new = decode_attention_chunked_paged(
+        p, cfg, x, pool, page_table, jnp.asarray([3, 2, 2]),
+        jnp.asarray([4, 3, 0]), layer=0)
+    for n in ("k", "v"):
+        assert _written(pages, _paged(new[n], Hkv, hd)) == [
+            [0, 1, 2], [0, 1, 3], [0, 4, 3], [0, 5, 0], [0, 5, 1],
+            [0, 5, 2]]
+
+
+def test_paged_write_touches_only_its_layer():
+    """The stacked (L, P, ...) pool is carried whole: layer ``l``'s
+    write changes layer ``l`` and leaves every other layer bitwise."""
+
+    cfg = get_config("smollm-135m").reduced().replace(
+        logits_dtype="float32")
+    p = init_params(attn_specs(cfg), jax.random.PRNGKey(1))
+    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    L, P, ps, T = 3, 6, 4, 5
+    rng = np.random.default_rng(5)
+    pages = rng.standard_normal((L, P, Hkv, ps, hd)).astype(np.float32)
+    pool = {"k": _pooled(cfg, pages), "v": _pooled(cfg, pages)}
+    page_table = jnp.asarray([[3, 0], [1, 4]], jnp.int32)
+    x = jnp.asarray(rng.standard_normal((2, T, cfg.d_model)), jnp.float32)
+    _, dec = decode_attention_paged(
+        p, cfg, x[:, :1], pool, page_table, jnp.asarray([1, 6]), layer=1)
+    _, chk = decode_attention_chunked_paged(
+        p, cfg, x, pool, page_table, jnp.asarray([0, 2]),
+        jnp.asarray([T, 3]), layer=1)
+    for new in (dec, chk):
+        for n in ("k", "v"):
+            after = _paged(new[n], Hkv, hd)
+            assert {w[0] for w in _written(pages, after)} == {1}
+
+
+def _one_hot_write(pages, page_table, cur_len, lengths, rows):
+    """NumPy model of the write the scatter replaced: a one-hot over
+    every (page, offset) of the pool picks its writer (b, t) by argmax
+    and a pool-wide select lands it.  pages: (P, Hkv, ps, hd); rows:
+    (B, Hkv, T, hd)."""
+
+    P, Hkv, ps, hd = pages.shape
+    B, _, T, _ = rows.shape
+    M = page_table.shape[1]
+    pos = cur_len[:, None] + np.arange(T)[None, :]
+    phys = np.take_along_axis(page_table, np.clip(pos // ps, 0, M - 1), 1)
+    phys = np.where(np.arange(T)[None, :] < lengths[:, None], phys, -1)
+    hot = ((phys[:, :, None] == np.arange(P))[..., None]
+           & ((pos % ps)[:, :, None] == np.arange(ps))[:, :, None, :])
+    hot = hot.reshape(B * T, P, ps)
+    written, writer = hot.any(axis=0), hot.argmax(axis=0)
+    flat = rows.transpose(0, 2, 1, 3).reshape(B * T, Hkv, hd)
+    vals = flat[writer].transpose(0, 2, 1, 3)          # (P, Hkv, ps, hd)
+    return np.where(written[:, None, :, None], vals, pages)
+
+
+@pytest.mark.parametrize("T", [1, 7])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_paged_scatter_matches_one_hot_select(T, seed):
+    """The indexed write is bit-identical to the one-hot select it
+    replaced, on shuffled page tables, bf16 pool, mixed lengths (0
+    included) and start offsets; T=1 is the decode write."""
+
+    from repro.models.attention import _write_pool
+
+    cfg = get_config("smollm-135m").reduced()
+    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    B, M, ps, P = 4, 5, 4, 24
+    rng = np.random.default_rng(seed)
+    cur_len = rng.integers(0, M * ps - T, B).astype(np.int32)
+    lengths = rng.integers(0, T + 1, B).astype(np.int32)
+    lengths[0] = T
+    perm = iter(rng.permutation(P).tolist())
+    page_table = np.full((B, M), -1, np.int32)
+    for b in range(B):
+        for m in range(-(-int(cur_len[b] + lengths[b]) // ps)):
+            page_table[b, m] = next(perm)
+    pages = rng.standard_normal((P, Hkv, ps, hd)).astype(jnp.bfloat16)
+    rows = rng.standard_normal((B, Hkv, T, hd)).astype(np.float32)
+
+    pool = _pooled(cfg, pages[None])
+    new = _write_pool({"k": pool, "v": pool}, 0, jnp.asarray(page_table),
+                      jnp.asarray(cur_len), jnp.asarray(lengths),
+                      jnp.asarray(rows), jnp.asarray(-rows))
+    want = _one_hot_write(pages, page_table, cur_len, lengths,
+                          rows.astype(jnp.bfloat16))
+    np.testing.assert_array_equal(_paged(new["k"], Hkv, hd)[0], want)
+    want = _one_hot_write(pages, page_table, cur_len, lengths,
+                          (-rows).astype(jnp.bfloat16))
+    np.testing.assert_array_equal(_paged(new["v"], Hkv, hd)[0], want)
 
 
 # ---------------------------------------------------------------------------
@@ -377,6 +539,35 @@ def test_paged_matches_contiguous_staggered_mixed_phase(arch, extra):
 
     for prompt, req in ((prompt_a, req_a), (prompt_b, req_b)):
         assert req.out == _solo_out(api, params, prompt, 4,
+                                    prefill_chunk=4)
+
+
+def test_paged_server_donates_state_and_keeps_tokens():
+    """The paged decode and prefill steps donate the server's state: the
+    state a tick hands them is deleted afterwards (the pool was written
+    in place), and a drain through a tick in which one slot decodes,
+    one prefills and one idles gives every request its solo tokens."""
+
+    cfg = get_config("smollm-135m").reduced().replace(
+        logits_dtype="float32")
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(12)
+    prompt_a = rng.integers(0, cfg.vocab, 5).tolist()
+    prompt_b = rng.integers(0, cfg.vocab, 11).tolist()
+
+    srv = Server(api, params, batch=3, context=32, prefill_chunk=4,
+                 paged=True, page_size=8)
+    req_a = srv.submit(prompt_a, max_new=6)
+    while not req_a.out:
+        srv.tick()               # A through its prefill
+    req_b = srv.submit(prompt_b, max_new=4)
+    handed = srv.state
+    srv.tick()                   # A decodes, B prefills, slot 2 idles
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(handed))
+    srv.run_until_drained()
+    for prompt, req, n in ((prompt_a, req_a, 6), (prompt_b, req_b, 4)):
+        assert req.out == _solo_out(api, params, prompt, n,
                                     prefill_chunk=4)
 
 

@@ -1,5 +1,6 @@
 """Compile-only checks for a described TPU v5e: the kernels' modeled block
-picks and smollm-135m's paged decode step at published widths.
+picks, smollm-135m's paged decode step at published widths, and the
+server's donated paged steps at qwen1.5-4b's serving shapes.
 
 Nothing here runs on a chip.  The TPU compiler (libtpu) compiles for a
 ``v5e:2x2`` topology described in a fixture, and refuses what the chip
@@ -115,3 +116,57 @@ def test_smollm_paged_decode_step_compiles(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 * 2**30
+
+
+@pytest.mark.parametrize("arch,batch,n_pages,step", [
+    ("qwen1.5-4b", 8, 512, "decode"),
+    ("qwen1.5-4b", 8, 512, "prefill"),
+    ("smollm-135m", 64, 4096, "decode"),
+], ids=["qwen15_4b-decode", "qwen15_4b-prefill", "smollm_135m-decode"])
+def test_paged_server_steps_write_the_pool_in_place(one_chip, arch, batch,
+                                                    n_pages, step):
+    """The server's jitted paged decode and prefill steps at the serving
+    cells' shapes (context 1024, page 16, chunk 128; qwen1.5-4b 8 slots
+    and 512 pages, smollm-135m 64 and 4,096) donate the state, so the
+    compiled program aliases the whole KV pool from input to output,
+    and no temporary comes near a copy of the stacked pool.  smollm's
+    64-wide heads are the case where a pool laid out by head size made
+    the TPU relayout the whole pool; its prefill is left out, its
+    8,192-query attention scores alone being larger than the bound."""
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.runtime.kv import PagedKVSpec
+    from repro.runtime.serve import server_steps
+
+    api = build_model(get_config(arch))
+    context, chunk = 1024, 128
+    spec = PagedKVSpec.for_server(context=context, page_size=16,
+                                  n_pages=n_pages, batch=batch)
+    params = _on(one_chip, api.abstract())
+    state = _on(one_chip, jax.eval_shape(
+        lambda: api.init_decode_state(batch, context, spec,
+                                      dtype=jnp.bfloat16)))
+    pool = [a for e in state["blocks"].values()
+            for a in jax.tree.leaves(e["kv"])]
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool)
+    layer_bytes = pool_bytes // pool[0].shape[0]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    table = i32(batch, spec.pages_per_slot)
+    decode, prefill, _ = server_steps(api, paged=True)
+    if step == "decode":
+        active = jax.ShapeDtypeStruct((batch,), jnp.bool_,
+                                      sharding=one_chip)
+        lowered = decode.lower(params, state, i32(batch, 1), i32(batch),
+                               active, table)
+    else:
+        lowered = prefill.lower(params, state, i32(batch, chunk),
+                                i32(batch), i32(batch), table)
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # the read path's float32 copy of a layer's gathered pages is one
+    # layer's K and V in size here (slots x pages a slot = the pool's
+    # pages), so the bound is two layers: a stacked pool copy is 15 to
+    # 20 times more
+    assert mem.temp_size_in_bytes < 2 * layer_bytes
