@@ -24,7 +24,9 @@ import json
 import shutil
 import sys
 import time
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field, fields, is_dataclass, replace
+from typing import Any
+
 import numpy as np
 
 from . import cost, devtrace, spec, stats, traffic
@@ -55,7 +57,7 @@ class RunData:
     ticks: list
     batch: int
     counters: dict
-    model: cost.Model
+    model: Any        # ``decode(keys)``, ``prefill(chunks)``: (flops, bytes)
     peaks: dict | None
     red: devtrace.Reduction | None = None
     traced_ticks: list = field(default_factory=list)
@@ -95,18 +97,72 @@ def use_cache() -> None:
     jax.config.update("jax_compilation_cache_max_size", -1)
 
 
+# published configuration keys and the ``ArchConfig`` field each sets
+ARCH_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
+             "num_attention_heads": "n_heads",
+             "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+             "intermediate_size": "d_ff", "vocab_size": "vocab",
+             "qkv_bias": "qkv_bias", "tie_word_embeddings": "tie_embeddings",
+             "rope_theta": "rope_theta", "sliding_window": "window"}
+
+
 def arch_config(cfg: dict):
-    """The program's ``ArchConfig`` at the sizes the file states."""
+    """The program's ``ArchConfig`` at the sizes the file states: the
+    registry's entry for ``arch``, each key of ``ARCH_KEYS`` the file
+    holds in its field, then the file's ``program`` object of field
+    overrides.  A field that holds a dataclass (``moe``, ``ssm``) takes a
+    dict of its own fields, applied to the registry's value.  An
+    override of no field is an error."""
 
     from repro.configs import get_config
 
-    return get_config(cfg["arch"]).replace(
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        qkv_bias=cfg["qkv_bias"], tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=cfg["rope_theta"], window=None)
+    arch = get_config(cfg["arch"])
+    kw = {f: cfg[k] for k, f in ARCH_KEYS.items() if k in cfg}
+    names = {f.name for f in fields(arch)}
+    for name, value in cfg.get("program", {}).items():
+        if name not in names:
+            raise KeyError(f'"program" overrides {name!r}, which is no field '
+                           f"of ArchConfig")
+        if isinstance(value, dict):
+            held = getattr(arch, name)
+            if not is_dataclass(held):
+                raise TypeError(f'"program" gives {name!r} fields, but '
+                                f"{cfg['arch']}'s {name} is {held!r}")
+            value = replace(held, **value)
+        kw[name] = value
+    return arch.replace(**kw)
+
+
+def cost_model(cfg: dict):
+    """The configuration's count of operations and bytes a step does:
+    the ``Model`` of ``bench/costs/<cost>.py`` where the file names a
+    ``cost``, else the dense decoder's (``bench/cost.py``)."""
+
+    if "cost" in cfg:
+        return spec.load_module("costs", cfg["cost"]).Model.from_config(cfg)
+    return cost.Model.from_config(cfg)
+
+
+def check_dtypes(tree, dtype: str, key: str, cfg: dict) -> None:
+    """Every leaf of ``tree`` in ``dtype``, but for those that the
+    configuration's table ``key`` lists by name with a dtype of their
+    own."""
+
+    from .weights import named_leaves
+
+    want = cfg.get(key, {})
+    leaves = named_leaves(tree)
+    unknown = sorted(set(want) - {n for n, _ in leaves})
+    if unknown:
+        raise KeyError(f'"{key}" names {unknown}, which the program does '
+                       f"not hold")
+    for name, a in leaves:
+        if str(a.dtype) != want.get(name, dtype):
+            raise ValueError(
+                f"the program holds {name!r} in {a.dtype}, the "
+                f"configuration states {want.get(name, dtype)}"
+                + ("" if name in want else
+                   f' (list a leaf in another dtype under "{key}")'))
 
 
 def sample(done: list, n: int, seed: int) -> list:
@@ -220,21 +276,16 @@ def build(cell: spec.Cell, seed: int):
     cfg = cell.config
     api = build_model(arch_config(cfg))
     abstract = jax.eval_shape(api.init, jax.random.PRNGKey(0))
-    dts = {str(a.dtype) for a in jax.tree.leaves(abstract)}
-    if dts != {cfg["dtype"]}:
-        raise ValueError(f"program declares params in {dts}, the "
-                         f"configuration states {cfg['dtype']}")
+    check_dtypes(abstract, cfg["dtype"], "param_dtypes", cfg)
     params = make_params(abstract, seed, d_model=cfg["hidden_size"],
-                         tied=cfg["tie_word_embeddings"])
+                         tied=cfg["tie_word_embeddings"],
+                         init=cfg.get("init", {}))
     s = cell.server
     server = Server(api, params, batch=s["slots"], context=s["context"],
                     prefill_chunk=s["prefill_chunk"], paged=True,
                     page_size=s["page_size"], kv_pages=s["kv_pages"],
                     scheduler=s["scheduler"])
-    kv = {str(a.dtype) for a in jax.tree.leaves(server.state)}
-    if kv != {cfg["kv_dtype"]}:
-        raise ValueError(f"KV pool in {kv}, the configuration states "
-                         f"{cfg['kv_dtype']}")
+    check_dtypes(server.state, cfg["kv_dtype"], "state_dtypes", cfg)
     return api, params, server
 
 
@@ -372,7 +423,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     run = RunData(cell=cell, seconds=seconds, setup_s=setup_s, t0=t0,
                   t1=t_end, recs=recs, ticks=drv.ticks,
                   batch=server.batch, counters=counters,
-                  model=cost.Model.from_config(cell.config), peaks=peaks,
+                  model=cost_model(cell.config), peaks=peaks,
                   red=red, traced_ticks=traced_ticks)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):

@@ -9,7 +9,27 @@ metric sits in a file of its own, found by name:
 * ``bench/cells/<workload>.json``   the cell's server settings, its
   correctness sample and limits;
 * ``bench/metrics/<metric>.py``     a reader with ``read(run)``;
-* ``bench/references/<name>.py``    a plain float32 reference forward.
+* ``bench/references/<name>.py``    a plain float32 reference forward;
+* ``bench/costs/<name>.py``         a ``Model`` with ``from_config(cfg)``,
+  ``decode(keys)`` and ``prefill(chunks)``: a configuration's count of
+  operations and bytes, which the roofline and MFU readers read.
+
+A configuration file may also hold these optional keys:
+
+* ``sliding_window``  the attention window; ``harness.arch_config``
+  sets ``ArchConfig.window`` from it (absent: the registry's);
+* ``program``         ``ArchConfig`` field overrides, a dataclass field's
+  as a dict of its fields (a chip share: experts held, a vocabulary
+  slice); read by ``harness.arch_config``;
+* ``init``            weight rules by leaf name, ``{"contract": [axes]}``
+  or ``{"mean": m, "std": s}``; read by ``weights.make_params`` before
+  its own;
+* ``param_dtypes``    leaf name to dtype, for parameters not in
+  ``dtype``; read by ``harness.check_dtypes`` in ``harness.build``;
+* ``state_dtypes``    the same for decode-state leaves not in
+  ``kv_dtype`` (an SSM's float32 recurrence); read there too;
+* ``cost``            the name of its ``bench/costs/<name>.py``; read by
+  ``harness.cost_model`` (absent: the dense decoder's ``bench/cost.py``).
 """
 
 from __future__ import annotations

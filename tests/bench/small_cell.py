@@ -3,14 +3,15 @@
 import json
 
 
-def small_cell(d: int, layers: int, vocab: int, limit: float):
+def small_cell(d: int, layers: int, vocab: int, limit: float,
+               config: str = "smollm-135m"):
     """A cell of the benchmark's kind at a size the CPU runs in seconds:
-    smollm-135m's file with its widths cut, a closed loop of 4 clients
-    on 4 slots, fcfs, paged bf16."""
+    a configuration's file (smollm-135m's unless named) with its widths
+    cut, a closed loop of 4 clients on 4 slots, fcfs, paged bf16."""
 
     from bench import spec
 
-    cfg = json.loads((spec.BENCH / "configs" / "smollm-135m.json")
+    cfg = json.loads((spec.BENCH / "configs" / f"{config}.json")
                      .read_text())
     heads = max(4, d // 64)
     cfg.update(hidden_size=d, intermediate_size=4 * d,
