@@ -16,11 +16,71 @@ from dataclasses import dataclass, field
 
 @dataclass(frozen=True)
 class MoECfg:
-    num_experts: int
+    num_experts: int            # the router's outputs
     top_k: int
     every: int = 1              # MoE layer every N layers (llama4: 2)
     capacity_factor: float = 1.25
     shared_experts: int = 0     # llama4: 1 shared expert
+    d_expert: int | None = None  # routed expert width (default d_ff);
+    #   the shared experts are one SwiGLU of shared_experts * d_expert
+    first_dense: int = 0        # leading dense layers before the MoE stack
+    renormalize: bool = True    # gates: softmax over the top-k (True) or
+    #   the top-k of a softmax over every expert, not renormalised
+    dropless: bool = False      # route every token (moe.held_experts_forward)
+    #   instead of the capacity-padded dispatch
+    held: int | None = None     # experts this chip holds (default all) ...
+    held_from: int = 0          # ... from this one: its expert-parallel share
+
+    def __post_init__(self):
+        if self.held is not None and not self.dropless:
+            raise ValueError("a held share of the experts needs the "
+                             "dropless layer (dropless=True)")
+        n = self.num_experts if self.held is None else self.held
+        if not 0 <= self.held_from <= self.num_experts - n:
+            raise ValueError(f"held experts {self.held_from}..."
+                             f"{self.held_from + n - 1} lie outside the "
+                             f"router's {self.num_experts}")
+
+    @property
+    def n_held(self) -> int:
+        return self.num_experts if self.held is None else self.held
+
+
+@dataclass(frozen=True)
+class MLACfg:
+    """Multi-head latent attention (DeepSeek-V2): keys and values come
+    from one normed latent of ``kv_lora_rank`` per token, and a query or
+    key head is ``qk_nope_head_dim`` unrotated dims plus
+    ``qk_rope_head_dim`` rotated ones, the rotated key shared by all
+    heads.  The cache holds the latent and the rotated key."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """Cached values a token a layer: the latent and the rope key."""
+
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnCfg:
+    """YaRN scaling of RoPE's frequencies (the ``rope_scaling`` of a
+    ``"type": "yarn"`` config)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -51,6 +111,8 @@ class ArchConfig:
     tie_embeddings: bool = False
     mlp_act: str = "swiglu"              # swiglu | gelu
     moe: MoECfg | None = None
+    mla: MLACfg | None = None            # latent attention in every layer
+    rope_scaling: YarnCfg | None = None  # YaRN rope of MLA's rope dims
     ssm: SSMCfg | None = None
     cross_attn_every: int | None = None  # vlm: 1 cross-attn per N layers
     n_img_tokens: int = 1024             # vlm stub frontend output length
@@ -93,9 +155,17 @@ class ArchConfig:
         )
         if self.window is not None:
             kw["window"] = 8
-        if self.moe is not None:
+        if self.moe is not None and self.moe.dropless:
+            kw["moe"] = dataclasses.replace(
+                self.moe, num_experts=8, top_k=min(self.moe.top_k, 3),
+                d_expert=32, held=None, held_from=0)
+            kw["n_layers"] = self.moe.first_dense + 2
+        elif self.moe is not None:
             kw["moe"] = dataclasses.replace(
                 self.moe, num_experts=4, top_k=min(self.moe.top_k, 2))
+        if self.mla is not None:
+            kw["mla"] = MLACfg(kv_lora_rank=32, qk_nope_head_dim=16,
+                               qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, state=8, headdim=8, chunk=8)
@@ -138,4 +208,5 @@ def supports(arch: ArchConfig, shape: ShapeSpec) -> tuple[bool, str]:
     return True, ""
 
 
-__all__ = ["ArchConfig", "ShapeSpec", "MoECfg", "SSMCfg", "SHAPES", "supports"]
+__all__ = ["ArchConfig", "ShapeSpec", "MoECfg", "MLACfg", "YarnCfg", "SSMCfg",
+           "SHAPES", "supports"]

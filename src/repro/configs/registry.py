@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from .base import ArchConfig, MoECfg, SSMCfg
+from .base import ArchConfig, MLACfg, MoECfg, SSMCfg, YarnCfg
 
 minitron_8b = ArchConfig(
     name="minitron-8b", family="dense", n_layers=32, d_model=4096,
@@ -71,10 +71,23 @@ whisper_medium = ArchConfig(
     source="enc-dec, conv frontend (stub) [arXiv:2212.04356; unverified] — "
            "input_specs() provides precomputed frame embeddings")
 
+deepseek_v2_lite = ArchConfig(
+    name="deepseek-v2-lite", family="moe", n_layers=27, d_model=2048,
+    n_heads=16, n_kv_heads=16, d_ff=10944, vocab=102_400,
+    mla=MLACfg(kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+               v_head_dim=128),
+    rope_scaling=YarnCfg(factor=40.0, original_max_position_embeddings=4096,
+                         beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                         mscale_all_dim=0.707),
+    moe=MoECfg(num_experts=64, top_k=6, shared_experts=2, d_expert=1408,
+               first_dense=1, renormalize=False, dropless=True),
+    source="MLA, 64 routed + 2 shared experts, first layer dense, YaRN "
+           "[hf:deepseek-ai/DeepSeek-V2-Lite config.json; arXiv:2405.04434]")
+
 ARCHS: dict[str, ArchConfig] = {c.name: c for c in [
     minitron_8b, qwen3_32b, qwen15_4b, smollm_135m, mamba2_27b,
     mixtral_8x22b, llama4_maverick, llama32_vision_90b, hymba_15b,
-    whisper_medium,
+    whisper_medium, deepseek_v2_lite,
 ]}
 
 

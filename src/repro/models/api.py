@@ -10,7 +10,9 @@
 * ``decode_step(params, state, tokens)`` — one-token serve step
 
 Decode state is a pytree with a stacked leading blocks dim, scanned in
-lock-step with the stacked block params.
+lock-step with the stacked block params; a model with leading layers
+(:func:`~repro.models.transformer._lead_plan`) has a ``"lead"`` stack
+of its own, scanned first.
 """
 
 from __future__ import annotations
@@ -28,8 +30,8 @@ from . import moe as moe_mod
 from . import ssm as ssm_mod
 from .common import (PSpec, abstract_params, axes_tree, init_params,
                      rms_norm, stack_specs)
-from .transformer import (_block_plan, _logits, _sinusoid, forward_encdec,
-                          forward_lm, lm_loss, mlp_forward,
+from .transformer import (_block_plan, _lead_plan, _logits, _sinusoid,
+                          forward_encdec, forward_lm, lm_loss, mlp_forward,
                           stack_param_specs)
 
 
@@ -77,7 +79,8 @@ class ModelAPI:
 
     # -- decode ---------------------------------------------------------
     def decode_block_specs(self, batch: int, context: int,
-                           paged: Any = None, dtype: Any = None) -> dict:
+                           paged: Any = None, dtype: Any = None,
+                           kinds: list[str] | None = None) -> dict:
         """Decode state of ONE block (unstacked) — also used by the
         dry-run's block-level cost lowering.
 
@@ -90,10 +93,11 @@ class ModelAPI:
         stay per-slot: they are O(1) in context, paging buys nothing.
         ``dtype`` overrides the KV storage dtype (default bfloat16) —
         pass the params' dtype to keep a float32 model float32 through
-        the cache."""
+        the cache.  ``kinds`` defaults to the block's
+        (:func:`_block_plan`)."""
 
         cfg = self.cfg
-        kinds, _ = _block_plan(cfg)
+        kinds = kinds or _block_plan(cfg)[0]
         C = _cache_len(cfg, context)
         per_block: dict[str, Any] = {}
         for i, kind in enumerate(kinds):
@@ -122,10 +126,28 @@ class ModelAPI:
 
     def decode_state_specs(self, batch: int, context: int,
                            paged: Any = None, dtype: Any = None) -> dict:
+        """The decode state: ``"blocks"`` stacked over the blocks, and
+        ``"lead"`` over the leading layers where the model has them.
+        Paged, there is one pool for every layer: the stacked ``"kv"``
+        of the block's attention holds the leading layers' pages first
+        (``(n_lead + n_blocks, P, R, W)``), and ``"lead"`` only their
+        per-slot state."""
+
         cfg = self.cfg
-        _, n_blocks = _block_plan(cfg)
+        kinds, n_blocks = _block_plan(cfg)
+        lead, n_lead = _lead_plan(cfg)
         per_block = self.decode_block_specs(batch, context, paged, dtype)
         state: dict[str, Any] = {"blocks": stack_specs(per_block, n_blocks)}
+        if n_lead:
+            per_lead = self.decode_block_specs(batch, context, paged, dtype,
+                                               kinds=lead)
+            if paged is not None:
+                key = _pool_key(cfg, kinds)
+                per_lead = {k: {n: v for n, v in e.items() if n != "kv"}
+                            for k, e in per_lead.items()}
+                state["blocks"][key]["kv"] = stack_specs(
+                    per_block[key]["kv"], n_lead + n_blocks)
+            state["lead"] = stack_specs(per_lead, n_lead)
         if cfg.is_encdec:
             Hkv, hd = cfg.n_kv_heads, cfg.hd
             xkv = {"k": PSpec((batch, Hkv, cfg.enc_seq, hd),
@@ -168,15 +190,17 @@ class ModelAPI:
         pool in place."""
 
         cfg = self.cfg
-        kinds, _ = _block_plan(cfg)
         x = jnp.take(params["embed"], tokens, axis=0)       # (B,1,d)
         cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32),
                                    (tokens.shape[0],))
         if cfg.is_encdec:
             x = x + _sinusoid_at(cur_len[:, None], cfg.d_model, x.dtype)
 
-        body = make_decode_body(cfg, kinds, cur_len, page_table, active)
-        x, new_state = _scan_layers(cfg, body, params, state, x,
+        def make(kinds, pool_of):
+            return make_decode_body(cfg, kinds, cur_len, page_table, active,
+                                    pool_of=pool_of)
+
+        x, new_state = _scan_layers(cfg, make, params, state, x,
                                     page_table is not None)
         return _logits(params, cfg, x)[:, 0], new_state
 
@@ -238,7 +262,6 @@ class ModelAPI:
         Returns ``(hidden (B, T, d), new state, lengths (B,))``."""
 
         cfg = self.cfg
-        kinds, _ = _block_plan(cfg)
         B, T = tokens.shape
         positions = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B,))
         if lengths is None:
@@ -250,9 +273,11 @@ class ModelAPI:
             pos_grid = positions[:, None] + jnp.arange(T, dtype=jnp.int32)
             x = x + _sinusoid_at(pos_grid, cfg.d_model, x.dtype)
 
-        body = make_prefill_body(cfg, kinds, positions, lengths, valid,
-                                 page_table)
-        x, new_state = _scan_layers(cfg, body, params, state, x,
+        def make(kinds, pool_of):
+            return make_prefill_body(cfg, kinds, positions, lengths, valid,
+                                     page_table, pool_of=pool_of)
+
+        x, new_state = _scan_layers(cfg, make, params, state, x,
                                     page_table is not None)
         return x, new_state, lengths
 
@@ -332,32 +357,61 @@ class ModelAPI:
                 **paged_specs}
 
 
-def _scan_layers(cfg: ArchConfig, body, params, state, x, paged: bool):
-    """The layer scan of ``decode_step`` and ``_chunk_forward``; returns
-    ``(hidden, new state)``.
+def _scan_layers(cfg: ArchConfig, make_body, params, state, x, paged: bool):
+    """The layer scans of ``decode_step`` and ``_chunk_forward``: the
+    leading layers' stack where the model has one, then the blocks'.
+    ``make_body(kinds, pool_of)`` builds a scan body (:func:`_make_body`).
+    Returns ``(hidden, new state)``.
 
     Per-slot state (KV rings, SSM, cross K/V) is scanned in lock-step
-    with the stacked block params and stacked again as the scan's
-    output.  Paged, every block's ``"kv"`` pool — already stacked
-    ``(L, P, R, W)`` — leaves ``xs``/``ys`` and rides in the carry next
-    to ``h``, with the layer index scanned as ``arange(L)``: each layer
-    writes its pages of the carried pool, and no scan output re-stacks
-    it."""
+    with the stacked params and stacked again as the scan's output.
+    Paged, every block's ``"kv"`` pool — already stacked ``(L, P, R,
+    W)`` — leaves ``xs``/``ys`` and rides in the carry next to ``h``,
+    with the layer index scanned: each layer writes its pages of the
+    carried pool, and no scan output re-stacks it.  The leading layers
+    take the pool's first indices and the blocks the rest."""
 
+    kinds, n = _block_plan(cfg)
+    lead, n_lead = _lead_plan(cfg)
+    new_state = dict(state)
     cross = ((params["xattn_blocks"], state["xattn"]) if cfg.is_encdec
              else ())
     with jax.named_scope("layers"):
         if not paged:
-            x, blocks = jax.lax.scan(
-                body, x, (params["blocks"], state["blocks"], *cross))
+            if n_lead:
+                x, new_state["lead"] = jax.lax.scan(
+                    make_body(lead, None), x,
+                    (params["lead"], state["lead"]))
+            x, new_state["blocks"] = jax.lax.scan(
+                make_body(kinds, None), x,
+                (params["blocks"], state["blocks"], *cross))
         else:
             pool, rest = _split_pool(state["blocks"])
-            n = jax.tree.leaves(params["blocks"])[0].shape[0]
+            if n_lead:
+                key = _pool_key(cfg, kinds)
+                (x, pool), new_state["lead"] = jax.lax.scan(
+                    make_body(lead, {f"{i}_{k}": key
+                                     for i, k in enumerate(lead)}),
+                    (x, pool),
+                    (jnp.arange(n_lead), params["lead"], state["lead"]))
             (x, pool), rest = jax.lax.scan(
-                body, (x, pool),
-                (jnp.arange(n), params["blocks"], rest, *cross))
-            blocks = _join_pool(pool, rest)
-    return x, {**state, "blocks": blocks}
+                make_body(kinds, None), (x, pool),
+                (jnp.arange(n_lead, n_lead + n), params["blocks"], rest,
+                 *cross))
+            new_state["blocks"] = _join_pool(pool, rest)
+    return x, new_state
+
+
+def _pool_key(cfg: ArchConfig, kinds: list[str]) -> str:
+    """The block key whose ``"kv"`` pool the leading layers share."""
+
+    keys = [f"{i}_{k}" for i, k in enumerate(kinds)
+            if k in ("dense", "moe", "hybrid", "encoder")]
+    if len(keys) != 1:
+        raise ValueError(f"{cfg.name}: leading layers share the block's "
+                         f"pool, and its block has {len(keys)} attention "
+                         f"layers, not one")
+    return keys[0]
 
 
 def _split_pool(blocks: dict) -> tuple[dict, dict]:
@@ -378,15 +432,21 @@ def _join_pool(pool: dict, rest: dict) -> dict:
 
 
 def _make_body(cfg: ArchConfig, kinds: list[str], one_attn, one_ssm,
-               paged: bool):
+               paged: bool, valid: jax.Array | None = None,
+               pool_of: dict | None = None):
     """One block over all its kinds — the scan body shared by decode and
     chunked prefill, which differ only in ``one_attn(p, hn, kv, layer)``
-    and ``one_ssm(p, hn, state)``.
+    and ``one_ssm(p, hn, state)``, and in ``valid`` ((B, T) or (B, 1):
+    the rows that are tokens, which the dropless expert layer alone
+    routes).
 
     Contiguous: ``body(h, (bp, cache[, xp, xkv])) -> (h, new_cache)``.
     Paged: ``body((h, pool), (layer, bp, cache[, xp, xkv])) -> ((h,
     pool), new_cache)``, the stacked pool carried (see
-    :func:`_scan_layers`) and ``cache`` without its ``"kv"``."""
+    :func:`_scan_layers`) and ``cache`` without its ``"kv"``; a key of
+    ``pool_of`` attends the pool of the key it maps to."""
+
+    pool_of = pool_of or {}
 
     def body(carry, scanned):
         if paged:
@@ -401,10 +461,11 @@ def _make_body(cfg: ArchConfig, kinds: list[str], one_attn, one_ssm,
             nc: dict[str, Any] = {}
             hn = rms_norm(h, p["ln1"])
             if kind in ("dense", "moe", "encoder", "hybrid"):
+                pk = pool_of.get(key, key)
                 a, kv = one_attn(p["attn"], hn,
-                                 pool[key] if paged else c["kv"], layer)
+                                 pool[pk] if paged else c["kv"], layer)
                 if paged:
-                    pool = {**pool, key: kv}
+                    pool = {**pool, pk: kv}
                 else:
                     nc["kv"] = kv
             if kind in ("ssm", "hybrid"):
@@ -423,7 +484,7 @@ def _make_body(cfg: ArchConfig, kinds: list[str], one_attn, one_ssm,
             if "ffn" in p:
                 h2 = rms_norm(h, p["ln2"])
                 if kind == "moe":
-                    h = h + moe_mod.moe_forward(p["ffn"], cfg, h2)
+                    h = h + moe_mod.moe_forward(p["ffn"], cfg, h2, valid)
                 else:
                     h = h + mlp_forward(p["ffn"], cfg, h2)
             new_cache[key] = nc
@@ -439,7 +500,8 @@ def _make_body(cfg: ArchConfig, kinds: list[str], one_attn, one_ssm,
 
 def make_decode_body(cfg: ArchConfig, kinds: list[str], cur_len: jax.Array,
                      page_table: jax.Array | None = None,
-                     active: jax.Array | None = None):
+                     active: jax.Array | None = None,
+                     pool_of: dict | None = None):
     """One decode block: the scan body of ``decode_step`` and the unit
     lowered by the dry-run's block-cost analysis.  With ``page_table``
     the attention state is the shared paged pool, carried (``active``
@@ -457,13 +519,15 @@ def make_decode_body(cfg: ArchConfig, kinds: list[str], cur_len: jax.Array,
     def one_ssm(p, hn, st):
         return ssm_mod.ssm_decode_step(p, cfg, hn, st)
 
-    return _make_body(cfg, kinds, one_attn, one_ssm, page_table is not None)
+    return _make_body(cfg, kinds, one_attn, one_ssm, page_table is not None,
+                      None if active is None else active[:, None], pool_of)
 
 
 def make_prefill_body(cfg: ArchConfig, kinds: list[str],
                       positions: jax.Array, lengths: jax.Array,
                       valid: jax.Array,
-                      page_table: jax.Array | None = None):
+                      page_table: jax.Array | None = None,
+                      pool_of: dict | None = None):
     """One chunked-prefill block: the scan body of ``prefill_step`` —
     the multi-token sibling of :func:`make_decode_body`.  Attention
     advances the chunk through :func:`attn.decode_attention_chunked`
@@ -483,7 +547,8 @@ def make_prefill_body(cfg: ArchConfig, kinds: list[str],
     def one_ssm(p, hn, st):
         return ssm_mod.ssm_prefill_step(p, cfg, hn, st, valid)
 
-    return _make_body(cfg, kinds, one_attn, one_ssm, page_table is not None)
+    return _make_body(cfg, kinds, one_attn, one_ssm, page_table is not None,
+                      valid, pool_of)
 
 
 def _sinusoid_at(pos: jax.Array, d: int, dtype) -> jax.Array:

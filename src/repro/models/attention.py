@@ -1,6 +1,9 @@
 """GQA attention: qk-norm / qkv-bias / sliding-window / RoPE variants,
-full-sequence (train / prefill), single-token cached decode, and
-chunked cached prefill (multi-token serving steps) paths.
+and DeepSeek-V2's multi-head latent attention (MLA); full-sequence
+(train / prefill), single-token cached decode, and chunked cached
+prefill (multi-token serving steps) paths.  The cached paths take a
+cache of one or more leaves of per-token rows: K and V, or MLA's one
+latent row, attended absorbed.
 
 Pure-JAX math by default (XLA fuses this well on TPU); the Pallas flash
 kernel (`repro.kernels.flash_attention`) is the opt-in runtime path via
@@ -21,12 +24,15 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from ..distribute.sharding import logical_constraint as lc
-from .common import DEFAULT_DTYPE, PSpec, rms_norm, rope
+from .common import (DEFAULT_DTYPE, PSpec, rms_norm, rope, rope_yarn,
+                     yarn_attn_factor)
 
 NEG_INF = -1e30
 
 
 def attn_specs(cfg: ArchConfig, cross: bool = False) -> dict:
+    if cfg.mla is not None and not cross:
+        return mla_specs(cfg)
     d, H, Hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     # q/k/v contract over d_model, so that is their fan-in (the default,
     # the last dim, is the head dim: q·k logits came out ~(d/hd)x too
@@ -77,6 +83,150 @@ def _sdpa(q, k, v, mask, scale):
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqs,bhsk->bhqk", p, v.astype(jnp.float32))
     return o.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+#
+# q = x·W_q splits per head into q_nope and q_pe; [c, k_pe] = x·W_kv_a,
+# c = rms_norm(c); per head k_nope = c·W_uk and v = c·W_uv.  rope (YaRN,
+# ``cfg.rope_scaling``) acts on q_pe and on the one k_pe all heads
+# share.  score = (q_nope·k_nope + q_pe·k_pe) · scale, the scale with
+# YaRN's m².  What a token caches is its row [c ‖ k_pe]: the
+# serving paths keep it as a cache of one "head" of width kv_lora_rank +
+# qk_rope_head_dim, and attend absorbed: q_lat = q_nope·W_ukᵀ scores the
+# latent directly, and o_lat = Σ p·c is lifted by W_uv after the sum.
+
+
+def mla_specs(cfg: ArchConfig) -> dict:
+    m, d, H = cfg.mla, cfg.d_model, cfg.n_heads
+    r = m.kv_lora_rank
+    return {
+        "wq": PSpec((d, H, m.qk_head_dim), ("embed", "heads", None),
+                    scale=d ** -0.5),
+        "wkv_a": PSpec((d, m.cache_dim), ("embed", None), scale=d ** -0.5),
+        "kv_norm": PSpec((r,), (None,), init="ones"),
+        "w_uk": PSpec((r, H, m.qk_nope_head_dim), (None, "heads", None),
+                      scale=r ** -0.5),
+        "w_uv": PSpec((r, H, m.v_head_dim), (None, "heads", None),
+                      scale=r ** -0.5),
+        "wo": PSpec((H, m.v_head_dim, d), ("heads", None, "embed")),
+    }
+
+
+def _mla_scale(cfg: ArchConfig) -> float:
+    return cfg.mla.qk_head_dim ** -0.5 * yarn_attn_factor(cfg.rope_scaling)
+
+
+def _mla_project(p: dict, cfg: ArchConfig, x: jax.Array, pos: jax.Array):
+    """x: (B, T, d) at positions pos (B, T) -> ``((q_nope, q_pe), {"ckv":
+    rows})``: the roped query parts (B, H, T, ·) and each token's cached
+    row [rms_norm(c) ‖ rope(k_pe)], (B, 1, T, cache_dim)."""
+
+    r, nope = cfg.mla.kv_lora_rank, cfg.mla.qk_nope_head_dim
+    q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"])
+    q_nope, q_pe = q[..., :nope], q[..., nope:]
+    kv = x @ p["wkv_a"]                                 # (B, T, cache_dim)
+    c = rms_norm(kv[..., :r], p["kv_norm"])
+    k_pe = rope_yarn(kv[:, None, :, r:], pos, cfg.rope_theta,
+                     cfg.rope_scaling)                  # (B, 1, T, rope)
+    q_pe = rope_yarn(q_pe, pos, cfg.rope_theta, cfg.rope_scaling)
+    row = jnp.concatenate([c[:, None], k_pe.astype(c.dtype)], axis=-1)
+    return (q_nope, q_pe), {"ckv": row}
+
+
+def mla_attention(p: dict, cfg: ArchConfig, x: jax.Array,
+                  positions: jax.Array, *, causal: bool = True) -> jax.Array:
+    """Full-sequence latent attention, decompressed: every head's keys
+    and values are lifted from the latent (train / prefill forward)."""
+
+    B, S, d = x.shape
+    r = cfg.mla.kv_lora_rank
+    (q_nope, q_pe), rows = _mla_project(p, cfg, x, positions)
+    row = rows["ckv"][:, 0]                             # (B, S, cache_dim)
+    c, k_pe = row[..., :r], row[:, None, :, r:]
+    k_nope = jnp.einsum("bsc,chn->bhsn", c, p["w_uk"])
+    v = jnp.einsum("bsc,chv->bhsv", c, p["w_uv"])
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1] + k_pe.shape[-1:])],
+        axis=-1)
+    if causal:
+        mask = positions[:, None, None, :] <= positions[:, None, :, None]
+    else:
+        mask = jnp.ones((1, 1, S, S), bool)
+    o = _sdpa(q, k, v, mask, _mla_scale(cfg))
+    out = jnp.einsum("bhsv,hvd->bsd", o, p["wo"])
+    return lc(out, "batch", "seq", "embed")
+
+
+def _mla_attend(p: dict, cfg: ArchConfig, q, kv: dict, mask: jax.Array,
+                dtype) -> jax.Array:
+    """Absorbed latent attention of queries ``q = (q_nope, q_pe)`` (B, H,
+    T, ·) over cached rows ``kv["ckv"]`` (B, 1, S, cache_dim) under
+    ``mask`` (B, T, S); returns (B, T, d)."""
+
+    q_nope, q_pe = q
+    r = cfg.mla.kv_lora_rank
+    with jax.named_scope("kv_read"):
+        lat = kv["ckv"][:, 0].astype(jnp.float32)       # (B, S, cache_dim)
+    with jax.named_scope("attend"):
+        with jax.named_scope("absorb"):
+            q_lat = jnp.einsum("bhtn,chn->bhtc", q_nope.astype(jnp.float32),
+                               p["w_uk"].astype(jnp.float32))
+        qc = jnp.concatenate([q_lat, q_pe.astype(jnp.float32)], axis=-1)
+        s = jnp.einsum("bhtc,bsc->bhts", qc * _mla_scale(cfg), lat)
+        s = jnp.where(mask[:, None], s, NEG_INF)
+        pr = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bhts,bsc->bhtc", pr, lat[..., :r])
+        with jax.named_scope("absorb"):
+            o = jnp.einsum("bhtc,chv->bhtv", o_lat,
+                           p["w_uv"].astype(jnp.float32))
+        return jnp.einsum("bhtv,hvd->btd", o.astype(dtype), p["wo"])
+
+
+# ---------------------------------------------------------------------------
+# What the cached paths share: new rows, and attention over cached ones
+# ---------------------------------------------------------------------------
+
+
+def _project(p: dict, cfg: ArchConfig, x: jax.Array, pos: jax.Array):
+    """x (B, T, d) at positions pos (B, T) -> (queries, new cache rows by
+    leaf, each (B, heads, T, width)): roped q and {"k", "v"}, or MLA's
+    query parts and {"ckv"}."""
+
+    if cfg.mla is not None:
+        return _mla_project(p, cfg, x, pos)
+    q, k, v = _project_qkv(p, cfg, x, x)
+    if cfg.use_rope:
+        q = rope(q, pos, cfg.rope_theta)
+        k = rope(k, pos, cfg.rope_theta)
+    return q, {"k": k, "v": v}
+
+
+def _attend(p: dict, cfg: ArchConfig, q, kv: dict, mask: jax.Array,
+            dtype) -> jax.Array:
+    """Queries over the cached rows ``kv`` (leaves (B, heads, S, width))
+    under ``mask`` (B, T, S), through the output projection: (B, T, d)."""
+
+    if cfg.mla is not None:
+        return _mla_attend(p, cfg, q, kv, mask, dtype)
+    o = _grouped_sdpa(q, kv["k"], kv["v"], mask[:, None, None],
+                      cfg.hd ** -0.5).astype(dtype)
+    return jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+
+
+def _row_shape(cfg: ArchConfig) -> tuple[int, int]:
+    """(heads, width) of one token's cached row in each cache leaf."""
+
+    if cfg.mla is not None:
+        return 1, cfg.mla.cache_dim
+    return cfg.n_kv_heads, cfg.hd
+
+
+def _cache_leaves(cfg: ArchConfig) -> tuple[str, ...]:
+    return ("ckv",) if cfg.mla is not None else ("k", "v")
 
 
 # Above this query length, attention processes queries in chunks so the
@@ -163,6 +313,8 @@ def attention(p: dict, cfg: ArchConfig, x: jax.Array, positions: jax.Array,
     shapes take the pure-JAX math.
     """
 
+    if cfg.mla is not None and x_kv is None:
+        return mla_attention(p, cfg, x, positions, causal=causal)
     B, S, d = x.shape
     cross = x_kv is not None
     xkv = x_kv if cross else x
@@ -214,16 +366,14 @@ def kv_cache_specs(cfg: ArchConfig, batch: int, cache_len: int,
     # callers match the cache to the params' compute dtype (a float32
     # model wants float32 K/V — quantizing through bfloat16 costs exact
     # greedy-parity guarantees downstream consumers rely on).
-    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    # An MLA cache is one leaf, "ckv", of one "head" holding each
+    # token's latent row.
+    Hkv, hd = _row_shape(cfg)
     dt = dtype if dtype is not None else DEFAULT_DTYPE
-    return {
-        "k": PSpec((batch, Hkv, cache_len, hd),
-                   ("cache_batch", "kv_heads", "cache_seq", "head_dim"),
-                   init="zeros", dtype=dt),
-        "v": PSpec((batch, Hkv, cache_len, hd),
-                   ("cache_batch", "kv_heads", "cache_seq", "head_dim"),
-                   init="zeros", dtype=dt),
-    }
+    return {n: PSpec((batch, Hkv, cache_len, hd),
+                     ("cache_batch", "kv_heads", "cache_seq", "head_dim"),
+                     init="zeros", dtype=dt)
+            for n in _cache_leaves(cfg)}
 
 
 def decode_attention(p: dict, cfg: ArchConfig, x: jax.Array, cache: dict,
@@ -239,22 +389,18 @@ def decode_attention(p: dict, cfg: ArchConfig, x: jax.Array, cache: dict,
     stored post-RoPE.  Returns (output, updated cache)."""
 
     B, one, d = x.shape
-    C = cache["k"].shape[2]
+    C = next(iter(cache.values())).shape[2]
     cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (B,))
     positions = cur_len[:, None]                  # (B, 1)
-    q, k_new, v_new = _project_qkv(p, cfg, x, x)
-    if cfg.use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
+    q, new = _project(p, cfg, x, positions)
 
     slot = jnp.mod(cur_len, C)                    # (B,) ring for SWA
     # one-hot masked update instead of dynamic_update_slice: elementwise,
     # so it stays local under ANY cache sharding (dynamic updates on a
     # sharded dim made GSPMD replicate the whole cache — §Perf cell B)
     hot = (jnp.arange(C)[None, :] == slot[:, None])[:, None, :, None]
-    k = jnp.where(hot, k_new.astype(cache["k"].dtype), cache["k"])
-    v = jnp.where(hot, v_new.astype(cache["v"].dtype), cache["v"])
-    new_cache = {"k": k, "v": v}
+    new_cache = {n: jnp.where(hot, new[n].astype(c.dtype), c)
+                 for n, c in cache.items()}
 
     # validity per slot: ring index i last held absolute position
     # cur_len[b] - ((slot[b] - i) mod C)
@@ -266,13 +412,11 @@ def decode_attention(p: dict, cfg: ArchConfig, x: jax.Array, cache: dict,
                 (abs_pos <= cl)
     else:
         valid = idx <= cl                         # (B, C)
-    mask = valid[:, None, None, None, :]          # (B, 1, 1, 1, C)
 
     # grouped GQA attention: contract q head-groups against the kv-head
     # cache directly — jnp.repeat's broadcast made GSPMD all-gather the
     # whole cache per layer (§Perf cell B, 8 GiB/block)
-    o = _grouped_sdpa(q, k, v, mask, cfg.hd ** -0.5).astype(x.dtype)
-    out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+    out = _attend(p, cfg, q, new_cache, valid[:, None, :], x.dtype)
     return lc(out, "batch", "seq", "embed"), new_cache
 
 
@@ -300,17 +444,14 @@ def decode_attention_chunked(p: dict, cfg: ArchConfig, x: jax.Array,
     scattered in ring order, last writer per slot winning."""
 
     B, T, d = x.shape
-    C = cache["k"].shape[2]
+    C = next(iter(cache.values())).shape[2]
     cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (B,))
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     t_idx = jnp.arange(T, dtype=jnp.int32)
     pos = cur_len[:, None] + t_idx[None, :]            # (B, T) absolute
     valid = t_idx[None, :] < lengths[:, None]          # (B, T)
 
-    q, k_new, v_new = _project_qkv(p, cfg, x, x)
-    if cfg.use_rope:
-        q = rope(q, pos, cfg.rope_theta)
-        k_new = rope(k_new, pos, cfg.rope_theta)
+    q, new = _project(p, cfg, x, pos)
 
     # ring scatter: chunk token t lands in slot pos[t] mod C; for each
     # ring index take the LAST valid writer (one-hot + argmax keeps the
@@ -325,8 +466,7 @@ def decode_attention_chunked(p: dict, cfg: ArchConfig, x: jax.Array,
         vals = jnp.take_along_axis(new, last_t[:, None, :, None], axis=2)
         return jnp.where(hit[:, None, :, None], vals.astype(old.dtype), old)
 
-    new_cache = {"k": scatter(k_new, cache["k"]),
-                 "v": scatter(v_new, cache["v"])}
+    new_cache = {n: scatter(new[n], c) for n, c in cache.items()}
 
     # pre-chunk snapshot key positions: ring index i last held absolute
     # position (cur_len-1) - ((slot_last - i) mod C); never-written
@@ -342,15 +482,12 @@ def decode_attention_chunked(p: dict, cfg: ArchConfig, x: jax.Array,
     mask = k_ok[:, None, :] & (kp[:, None, :] <= qp)   # (B, T, C+T)
     if window is not None:
         mask &= kp[:, None, :] >= qp - window + 1
-    mask = mask[:, None, None, :, :]                   # (B, 1, 1, T, C+T)
 
     # grouped GQA over snapshot-cache + in-chunk keys (no head repeat)
-    k_all = jnp.concatenate([cache["k"].astype(jnp.float32),
-                             k_new.astype(jnp.float32)], axis=2)
-    v_all = jnp.concatenate([cache["v"].astype(jnp.float32),
-                             v_new.astype(jnp.float32)], axis=2)
-    o = _grouped_sdpa(q, k_all, v_all, mask, cfg.hd ** -0.5).astype(x.dtype)
-    out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+    every = {n: jnp.concatenate([c.astype(jnp.float32),
+                                 new[n].astype(jnp.float32)], axis=2)
+             for n, c in cache.items()}
+    out = _attend(p, cfg, q, every, mask, x.dtype)
     return lc(out, "batch", "seq", "embed"), new_cache
 
 
@@ -376,15 +513,16 @@ def kv_pool_specs(cfg: ArchConfig, n_pages: int, page_size: int,
     relayouts the whole pool.  The decode state stacks the pool over
     layers; the paged attention paths take that stacked ``(L, P, R,
     W)`` pool and a layer index.  ``dtype`` as in
-    :func:`kv_cache_specs`."""
+    :func:`kv_cache_specs`.  An MLA pool is one leaf, ``"ckv"``: a page
+    of ``page_size`` latent rows of ``kv_lora_rank + qk_rope_head_dim``."""
 
-    Hkv, hd = cfg.n_kv_heads, cfg.hd
+    Hkv, hd = _row_shape(cfg)
     dt = dtype if dtype is not None else DEFAULT_DTYPE
     E = Hkv * page_size * hd
     W = math.gcd(E, 128)
     return {n: PSpec((n_pages, E // W, W),
                      ("cache_batch", None, None), init="zeros", dtype=dt)
-            for n in ("k", "v")}
+            for n in _cache_leaves(cfg)}
 
 
 def _pool_page_size(pool_kv: jax.Array, Hkv: int, hd: int) -> int:
@@ -410,11 +548,11 @@ def _gather_pool(pool_kv: jax.Array, layer: jax.Array,
 
 
 def _write_pool(pool: dict, layer: jax.Array, page_table: jax.Array,
-                cur_len: jax.Array, lengths: jax.Array, k_new: jax.Array,
-                v_new: jax.Array) -> dict:
-    """Write each slot's first ``lengths[b]`` new K/V rows (k_new/v_new:
-    (B, Hkv, T, hd), token ``t`` at position ``cur_len[b] + t``) into
-    layer ``layer`` of the stacked pool, touching only their pages.
+                cur_len: jax.Array, lengths: jax.Array, new: dict) -> dict:
+    """Write each slot's first ``lengths[b]`` new rows (``new``: a (B,
+    Hkv, T, hd) array for each leaf of the pool, token ``t`` at position
+    ``cur_len[b] + t``) into layer ``layer`` of the stacked pool,
+    touching only their pages.
 
     A run of T tokens spans at most ``nP = (T + 2*ps - 2) // ps``
     pages.  Those pages are gathered by index, the new rows merged in
@@ -430,9 +568,10 @@ def _write_pool(pool: dict, layer: jax.Array, page_table: jax.Array,
     jit boundary, XLA updates it in place: the bytes written scale
     with B·T, not with the pool."""
 
-    B, Hkv, T, hd = k_new.shape
-    P, R, W = pool["k"].shape[1:]
-    ps = _pool_page_size(pool["k"], Hkv, hd)
+    name = next(iter(pool))
+    B, Hkv, T, hd = new[name].shape
+    P, R, W = pool[name].shape[1:]
+    ps = _pool_page_size(pool[name], Hkv, hd)
     M = page_table.shape[1]
     nP = (T + 2 * ps - 2) // ps
     first = cur_len % ps                                # (B,)
@@ -446,8 +585,8 @@ def _write_pool(pool: dict, layer: jax.Array, page_table: jax.Array,
     fresh = ((t >= 0) & (t < lengths[:, None])).reshape(B, nP, 1, ps, 1)
     src = jnp.clip(t, 0, T - 1)[:, None, :, None]       # (B, 1, nP*ps, 1)
 
-    def put(pool_n, new):
-        rows = jnp.take_along_axis(new, src, axis=2)    # (B,Hkv,nP*ps,hd)
+    def put(pool_n, new_n):
+        rows = jnp.take_along_axis(new_n, src, axis=2)  # (B,Hkv,nP*ps,hd)
         rows = rows.reshape(B, Hkv, nP, ps, hd).transpose(0, 2, 1, 3, 4)
         old = pool_n[layer, jnp.minimum(phys, P - 1)].reshape(
             B, nP, Hkv, ps, hd)
@@ -455,7 +594,7 @@ def _write_pool(pool: dict, layer: jax.Array, page_table: jax.Array,
         return pool_n.at[layer, phys].set(pages.reshape(B, nP, R, W),
                                           mode="drop")
 
-    return {"k": put(pool["k"], k_new), "v": put(pool["v"], v_new)}
+    return {n: put(a, new[n]) for n, a in pool.items()}
 
 
 def _pool_validity(page_table: jax.Array, page_size: int) -> jax.Array:
@@ -512,38 +651,32 @@ def decode_attention_paged(p: dict, cfg: ArchConfig, x: jax.Array,
     :meth:`ModelAPI.decode_step`)."""
 
     B, one, d = x.shape
-    Hkv, hd = cfg.n_kv_heads, cfg.hd
-    ps = _pool_page_size(pool["k"], Hkv, hd)
+    Hkv, hd = _row_shape(cfg)
+    ps = _pool_page_size(next(iter(pool.values())), Hkv, hd)
     M = page_table.shape[1]
     cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (B,))
     if active is None:
         active = jnp.ones((B,), bool)
     positions = cur_len[:, None]                  # (B, 1)
     with jax.named_scope("qkv"):
-        q, k_new, v_new = _project_qkv(p, cfg, x, x)
-        if cfg.use_rope:
-            q = rope(q, positions, cfg.rope_theta)
-            k_new = rope(k_new, positions, cfg.rope_theta)
+        q, new = _project(p, cfg, x, positions)
 
     with jax.named_scope("kv_write"):
         new_pool = _write_pool(pool, layer, page_table, cur_len,
-                               active.astype(jnp.int32), k_new, v_new)
+                               active.astype(jnp.int32), new)
 
     # gather the slot's linear view from the UPDATED pool; position t
     # lives at page t//ps — validity is the same absolute-position mask
     # as the contiguous path plus "is the page live"
-    k = _gather_pool(new_pool["k"], layer, page_table, Hkv, hd)
-    v = _gather_pool(new_pool["v"], layer, page_table, Hkv, hd)
+    kv = {n: _gather_pool(a, layer, page_table, Hkv, hd)
+          for n, a in new_pool.items()}
     with jax.named_scope("attend"):
         t = jnp.arange(M * ps, dtype=jnp.int32)[None, :]    # (1, M*ps)
         cl = cur_len[:, None]                               # (B, 1)
         valid = (t <= cl) & _pool_validity(page_table, ps)
         if window is not None:
             valid &= t >= cl - window + 1
-        mask = valid[:, None, None, None, :]      # (B, 1, 1, 1, M*ps)
-
-        o = _grouped_sdpa(q, k, v, mask, cfg.hd ** -0.5).astype(x.dtype)
-        out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+        out = _attend(p, cfg, q, kv, valid[:, None, :], x.dtype)
     return lc(out, "batch", "seq", "embed"), new_pool
 
 
@@ -567,8 +700,8 @@ def decode_attention_chunked_paged(p: dict, cfg: ArchConfig, x: jax.Array,
     goes to an out-of-range index that the scatter drops."""
 
     B, T, d = x.shape
-    Hkv, hd = cfg.n_kv_heads, cfg.hd
-    ps = _pool_page_size(pool["k"], Hkv, hd)
+    Hkv, hd = _row_shape(cfg)
+    ps = _pool_page_size(next(iter(pool.values())), Hkv, hd)
     cur_len = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (B,))
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     t_idx = jnp.arange(T, dtype=jnp.int32)
@@ -576,31 +709,25 @@ def decode_attention_chunked_paged(p: dict, cfg: ArchConfig, x: jax.Array,
     M = page_table.shape[1]
 
     with jax.named_scope("qkv"):
-        q, k_new, v_new = _project_qkv(p, cfg, x, x)
-        if cfg.use_rope:
-            q = rope(q, pos, cfg.rope_theta)
-            k_new = rope(k_new, pos, cfg.rope_theta)
+        q, new = _project(p, cfg, x, pos)
 
     with jax.named_scope("kv_write"):
         new_pool = _write_pool(pool, layer, page_table, cur_len, lengths,
-                               k_new, v_new)
+                               new)
 
     # chunk-causal read over the updated pool: key position t is valid
     # for query position qp when t <= qp (all such positions are
     # written — this request's earlier ticks or this chunk) and its
     # page is live
-    k = _gather_pool(new_pool["k"], layer, page_table, Hkv, hd)
-    v = _gather_pool(new_pool["v"], layer, page_table, Hkv, hd)
+    kv = {n: _gather_pool(a, layer, page_table, Hkv, hd)
+          for n, a in new_pool.items()}
     with jax.named_scope("attend"):
         kp = jnp.arange(M * ps, dtype=jnp.int32)[None, None, :]
         qp = pos[:, :, None]                           # (B, T, 1)
         mask = (kp <= qp) & _pool_validity(page_table, ps)[:, None, :]
         if window is not None:
             mask &= kp >= qp - window + 1
-        mask = mask[:, None, None, :, :]               # (B,1,1,T,M*ps)
-
-        o = _grouped_sdpa(q, k, v, mask, cfg.hd ** -0.5).astype(x.dtype)
-        out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+        out = _attend(p, cfg, q, kv, mask, x.dtype)
     return lc(out, "batch", "seq", "embed"), new_pool
 
 
@@ -618,7 +745,8 @@ def decode_cross_attention(p: dict, cfg: ArchConfig, x: jax.Array,
     return jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
 
 
-__all__ = ["attn_specs", "attention", "decode_attention",
+__all__ = ["attn_specs", "mla_specs", "attention", "mla_attention",
+           "decode_attention",
            "decode_attention_chunked", "decode_attention_paged",
            "decode_attention_chunked_paged", "kv_cache_specs",
            "kv_pool_specs", "decode_cross_attention"]

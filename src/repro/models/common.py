@@ -13,6 +13,7 @@ names + init).  The same spec tree serves three consumers:
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -115,6 +116,77 @@ def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Arra
     return rotated.astype(x.dtype)
 
 
+# YaRN (arXiv:2309.00071) as DeepSeek-V2's modelling code computes it:
+# below the correction range the frequencies keep their extrapolated
+# value, above it they are divided by ``factor``, and a linear ramp
+# over the range blends the two.
+
+
+def yarn_correction_range(dim: int, theta: float, yarn) -> tuple[int, int]:
+    """The rotary pair indices between which the ramp runs: where a pair
+    turns ``beta_fast`` and ``beta_slow`` times over the original
+    context."""
+
+    def dim_of(rotations):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = math.floor(dim_of(yarn.beta_fast))
+    high = math.ceil(dim_of(yarn.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """(dim // 2,) float32 inverse frequencies of a YaRN-scaled rope."""
+
+    expo = np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim)
+    extra = np.float32(1.0) / np.float32(theta) ** expo
+    inter = extra / np.float32(yarn.factor)
+    low, high = yarn_correction_range(dim, theta, yarn)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / np.float32(high - low), 0, 1)
+    keep = np.float32(1.0) - ramp         # 1: extrapolate, 0: interpolate
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_attn_factor(yarn) -> float:
+    """The factor on the softmax scale: ``mscale(mscale_all_dim)`` squared
+    (1 without ``mscale_all_dim``)."""
+
+    if yarn is None or not yarn.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def rope_yarn(x: jax.Array, positions: jax.Array, theta: float,
+              yarn) -> jax.Array:
+    """Rotary embedding on interleaved pairs ``(x[2i], x[2i+1])`` at the
+    YaRN frequencies, cos and sin times ``mscale / mscale_all_dim``
+    (DeepSeek-V2).  The output is in the published order: the pairs'
+    first elements, then their second.  x: (..., S, D); positions:
+    (..., S), broadcast over head dims as :func:`rope`."""
+
+    d = x.shape[-1]
+    freq = jnp.asarray(yarn_inv_freq(d, theta, yarn))
+    ratio = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    ang = positions[..., None].astype(jnp.float32) * freq    # (..., S, d/2)
+    cos, sin = jnp.cos(ang) * ratio, jnp.sin(ang) * ratio
+    while cos.ndim < x.ndim:
+        cos, sin = cos[..., None, :, :], sin[..., None, :, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
            w_down: jax.Array) -> jax.Array:
     h = jax.nn.silu(x @ w_gate) * (x @ w_up)
@@ -132,6 +204,7 @@ def softmax_cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 __all__ = [
     "PSpec", "is_pspec", "stack_specs", "init_params", "abstract_params",
-    "axes_tree", "rms_norm", "rope", "swiglu", "softmax_cross_entropy",
+    "axes_tree", "rms_norm", "rope", "rope_yarn", "yarn_inv_freq",
+    "yarn_attn_factor", "swiglu", "softmax_cross_entropy",
     "DEFAULT_DTYPE",
 ]

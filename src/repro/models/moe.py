@@ -10,6 +10,13 @@ tuning parameter of DESIGN.md §4.
 
 A reference one-hot einsum dispatch (``moe_forward_einsum``) validates
 the scatter path numerically on small shapes.
+
+``MoECfg.dropless`` selects the other layer, :func:`held_experts_forward`
+(DeepSeek-V2): every token is routed, none dropped, and the layer
+computes the part of the result that the experts it holds give — an
+expert-parallel share ``held_from .. held_from + held - 1`` of the
+router's outputs, all of them by default — as grouped matmuls over the
+rows routed to each (``jax.lax.ragged_dot``).
 """
 
 from __future__ import annotations
@@ -23,18 +30,24 @@ from .common import PSpec
 
 
 def moe_specs(cfg: ArchConfig) -> dict:
-    d, f = cfg.d_model, cfg.d_ff
-    E = cfg.moe.num_experts
+    """The router over all ``num_experts``; the weights of the experts
+    held (``n_held``) at width ``d_expert``; the shared experts as one
+    SwiGLU of ``shared_experts * d_expert``."""
+
+    m, d = cfg.moe, cfg.d_model
+    f = m.d_expert or cfg.d_ff
+    E, n = m.num_experts, m.n_held
     specs = {
         "router": PSpec((d, E), ("embed", "experts"), dtype=jnp.float32),
-        "wg": PSpec((E, d, f), ("experts", "embed", "expert_mlp")),
-        "wu": PSpec((E, d, f), ("experts", "embed", "expert_mlp")),
-        "wd": PSpec((E, f, d), ("experts", "expert_mlp", "embed")),
+        "wg": PSpec((n, d, f), ("experts", "embed", "expert_mlp")),
+        "wu": PSpec((n, d, f), ("experts", "embed", "expert_mlp")),
+        "wd": PSpec((n, f, d), ("experts", "expert_mlp", "embed")),
     }
-    if cfg.moe.shared_experts:
-        specs["shared_wg"] = PSpec((d, f), ("embed", "mlp"))
-        specs["shared_wu"] = PSpec((d, f), ("embed", "mlp"))
-        specs["shared_wd"] = PSpec((f, d), ("mlp", "embed"))
+    if m.shared_experts:
+        fs = m.shared_experts * f
+        specs["shared_wg"] = PSpec((d, fs), ("embed", "mlp"))
+        specs["shared_wu"] = PSpec((d, fs), ("embed", "mlp"))
+        specs["shared_wd"] = PSpec((fs, d), ("mlp", "embed"))
     return specs
 
 
@@ -49,13 +62,81 @@ def _route(cfg: ArchConfig, p: dict, x2: jax.Array):
 
     logits = (x2.astype(jnp.float32) @ p["router"]).astype(jnp.float32)
     k = cfg.moe.top_k
+    if not cfg.moe.renormalize:
+        # softmax over every expert, then its top-k as they are
+        return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
     top, idx = jax.lax.top_k(logits, k)
     gates = jax.nn.softmax(top, axis=-1)  # renormalize over the top-k
     return gates, idx
 
 
-def moe_forward(p: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
-    """x: (B, S, d) -> (B, S, d).
+def _shared(p: dict, x2: jax.Array) -> jax.Array:
+    return (jax.nn.silu(x2 @ p["shared_wg"]) * (x2 @ p["shared_wu"])
+            ) @ p["shared_wd"]
+
+
+def held_routed(p: dict, cfg: ArchConfig, x2: jax.Array,
+                valid: jax.Array | None = None) -> jax.Array:
+    """The routed part of the dropless layer that this chip's experts
+    give: x2 (T, d) -> (T, d), ``Σ gate_e · E_e(x)`` over the top-k
+    experts ``e`` of each token that lie in the held share.  Rows where
+    ``valid`` (T,) is False (padding, idle slots) are routed to no one.
+
+    The (token, choice) pairs are sorted by held expert, those of no
+    held expert last; the held ones' rows go through the experts as
+    three grouped matmuls, and are put back in pair order (a gather,
+    not a scatter-add) and summed over each token's choices.  No
+    capacity: the shapes are bounded by T·k.  The grouped matmuls
+    promise nothing for the rows past the held groups' total (on a TPU
+    they are left unwritten), so a pair's output is selected by
+    ``mine``, never multiplied by a zero gate."""
+
+    m = cfg.moe
+    T, d = x2.shape
+    k, n = m.top_k, m.n_held
+    with jax.named_scope("route"):
+        gates, idx = _route(cfg, p, x2)                 # (T, k)
+        local = idx - m.held_from
+        mine = (local >= 0) & (local < n)
+        if valid is not None:
+            mine &= valid[:, None]
+        e = jnp.where(mine, local, n).reshape(T * k)    # n: no held expert
+        order = jnp.argsort(e, stable=True)
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[e].add(1)[:n]
+        xs = jnp.take(x2, order // k, axis=0)           # (T*k, d)
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["wg"], sizes)) * \
+            jax.lax.ragged_dot(xs, p["wu"], sizes)
+        y = jax.lax.ragged_dot(h, p["wd"], sizes)       # (T*k, d), sorted
+        y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, k, d)
+        out = jnp.where(mine[..., None],
+                        y.astype(jnp.float32) * gates[..., None], 0.0)
+        return out.sum(axis=1).astype(x2.dtype)
+
+
+def held_experts_forward(p: dict, cfg: ArchConfig, x: jax.Array,
+                         valid: jax.Array | None = None) -> jax.Array:
+    """The dropless layer: x (B, S, d) -> (B, S, d), the held experts'
+    part (:func:`held_routed`) plus the shared experts, which every
+    chip computes alike.  ``valid`` (B, S) marks the rows that are
+    tokens."""
+
+    B, S, d = x.shape
+    x2 = x.reshape(B * S, d)
+    y = held_routed(p, cfg, x2,
+                    None if valid is None else valid.reshape(B * S))
+    if cfg.moe.shared_experts:
+        y = y + _shared(p, x2)
+    return y.reshape(B, S, d)
+
+
+@jax.named_scope("mlp")
+def moe_forward(p: dict, cfg: ArchConfig, x: jax.Array,
+                valid: jax.Array | None = None) -> jax.Array:
+    """x: (B, S, d) -> (B, S, d).  ``MoECfg.dropless`` takes
+    :func:`held_experts_forward` (``valid``: (B, S), the rows that are
+    tokens); otherwise the capacity dispatch below, which routes every
+    row.
 
     GShard-style *group-wise* dispatch with the batch row as the group:
     tokens compete for expert capacity only within their own sequence, so
@@ -65,6 +146,8 @@ def moe_forward(p: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     dispatch keeps HLO FLOPs at the model level (one-hot einsum dispatch
     is quadratic in tokens)."""
 
+    if cfg.moe.dropless:
+        return held_experts_forward(p, cfg, x, valid)
     B, S, d = x.shape
     E, k = cfg.moe.num_experts, cfg.moe.top_k
     C = capacity(cfg, S)                                  # per-row capacity
@@ -147,4 +230,5 @@ def moe_forward_einsum(p: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     return y
 
 
-__all__ = ["moe_specs", "moe_forward", "moe_forward_einsum", "capacity"]
+__all__ = ["moe_specs", "moe_forward", "moe_forward_einsum", "capacity",
+           "held_routed", "held_experts_forward"]

@@ -5,7 +5,9 @@ configurable remat.
 Layer kinds (picked per arch family):
 
 * dense   — pre-norm attention + MLP (minitron, qwen3, qwen1.5, smollm)
-* moe     — attention + MoE FFN (mixtral every layer; llama4 every 2nd)
+* moe     — attention + MoE FFN (mixtral every layer; llama4 every 2nd;
+            deepseek-v2-lite: latent attention, a dropless layer over
+            its held experts, after one leading dense layer)
 * ssm     — Mamba2 SSD block + (optional) MLP; d_ff == 0 -> pure SSD stack
 * hybrid  — parallel attention (SWA) and SSD heads on the same input,
             learned per-dim mix (hymba)
@@ -132,9 +134,27 @@ def layer_forward(p: dict, cfg: ArchConfig, kind: str, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _periods(cfg: ArchConfig, layers: int, every: int, what: str) -> int:
+    if layers % every:
+        raise ValueError(f"{cfg.name}: {layers} layers are no whole number "
+                         f"of periods of {every} ({what})")
+    return layers // every
+
+
+def _lead_plan(cfg: ArchConfig) -> tuple[list[str], int]:
+    """(kinds, number) of the leading layers that run before the scanned
+    blocks, as a stack of their own: ``MoECfg.first_dense`` dense layers
+    (deepseek-v2-lite's first), else none."""
+
+    if cfg.moe is not None and cfg.moe.first_dense:
+        return ["dense"], cfg.moe.first_dense
+    return [], 0
+
+
 def _block_plan(cfg: ArchConfig) -> tuple[list[str], int]:
     """Returns (kinds within one block, number of blocks).  The stack is
-    ``n_blocks`` repetitions of the block, scanned."""
+    ``n_blocks`` repetitions of the block, scanned, after the leading
+    layers of :func:`_lead_plan`."""
 
     if cfg.family == "dense":
         return ["dense"], cfg.n_layers
@@ -144,14 +164,15 @@ def _block_plan(cfg: ArchConfig) -> tuple[list[str], int]:
         return ["hybrid"], cfg.n_layers
     if cfg.family == "moe":
         every = cfg.moe.every
+        layers = cfg.n_layers - _lead_plan(cfg)[1]
         if every == 1:
-            return ["moe"], cfg.n_layers
-        assert cfg.n_layers % every == 0
-        return ["dense"] * (every - 1) + ["moe"], cfg.n_layers // every
+            return ["moe"], layers
+        return (["dense"] * (every - 1) + ["moe"],
+                _periods(cfg, layers, every, "MoE layer every"))
     if cfg.family == "vlm":
         every = cfg.cross_attn_every
-        assert cfg.n_layers % every == 0
-        return ["dense"] * (every - 1) + ["cross"], cfg.n_layers // every
+        return (["dense"] * (every - 1) + ["cross"],
+                _periods(cfg, cfg.n_layers, every, "cross-attention every"))
     if cfg.family == "audio":
         return ["dense"], cfg.n_layers      # decoder; encoder built apart
     raise ValueError(cfg.family)
@@ -166,6 +187,11 @@ def stack_param_specs(cfg: ArchConfig) -> dict:
         "ln_f": _norm_spec(cfg),
         "blocks": stack_specs(block, n_blocks),
     }
+    lead, n_lead = _lead_plan(cfg)
+    if n_lead:
+        specs["lead"] = stack_specs(
+            {f"{i}_{kind}": layer_specs(cfg, kind)
+             for i, kind in enumerate(lead)}, n_lead)
     if not cfg.tie_embeddings:
         specs["unembed"] = PSpec((cfg.d_model, cfg.vocab), ("embed", "vocab"))
     if cfg.family == "audio":
@@ -204,6 +230,16 @@ def _scan_blocks(cfg: ArchConfig, blocks, x, positions, *, enc_out=None,
     return x
 
 
+def _scan_stack(cfg: ArchConfig, params: dict, x, positions, enc_out=None):
+    """The decoder's layers: the leading ones, if any, then the blocks."""
+
+    if "lead" in params:
+        x = _scan_blocks(cfg, params["lead"], x, positions,
+                         kinds=_lead_plan(cfg)[0])
+    return _scan_blocks(cfg, params["blocks"], x, positions,
+                        enc_out=enc_out)
+
+
 @jax.named_scope("head")
 def _logits(params: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["ln_f"])
@@ -228,7 +264,7 @@ def forward_lm(params: dict, cfg: ArchConfig, tokens: jax.Array,
     x = jnp.take(params["embed"], tokens, axis=0)
     x = lc(x, "batch", "seq", "embed")
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = _scan_blocks(cfg, params["blocks"], x, positions, enc_out=img_embeds)
+    x = _scan_stack(cfg, params, x, positions, enc_out=img_embeds)
     return _logits(params, cfg, x)
 
 
@@ -282,7 +318,7 @@ def hidden_lm(params: dict, cfg: ArchConfig, tokens: jax.Array,
     x = jnp.take(params["embed"], tokens, axis=0)
     x = lc(x, "batch", "seq", "embed")
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = _scan_blocks(cfg, params["blocks"], x, positions, enc_out=img_embeds)
+    x = _scan_stack(cfg, params, x, positions, enc_out=img_embeds)
     return rms_norm(x, params["ln_f"])
 
 

@@ -486,7 +486,7 @@ def test_paged_scatter_matches_one_hot_select(T, seed):
     pool = _pooled(cfg, pages[None])
     new = _write_pool({"k": pool, "v": pool}, 0, jnp.asarray(page_table),
                       jnp.asarray(cur_len), jnp.asarray(lengths),
-                      jnp.asarray(rows), jnp.asarray(-rows))
+                      {"k": jnp.asarray(rows), "v": jnp.asarray(-rows)})
     want = _one_hot_write(pages, page_table, cur_len, lengths,
                           rows.astype(jnp.bfloat16))
     np.testing.assert_array_equal(_paged(new["k"], Hkv, hd)[0], want)

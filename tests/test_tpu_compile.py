@@ -170,3 +170,41 @@ def test_paged_server_steps_write_the_pool_in_place(one_chip, arch, batch,
     # pages), so the bound is two layers: a stacked pool copy is 15 to
     # 20 times more
     assert mem.temp_size_in_bytes < 2 * layer_bytes
+
+
+def test_deepseek_paged_decode_step_fits_its_cell(one_chip):
+    """deepseek-v2-lite at its file's chip share (16 of 64 experts held)
+    and the long_decode cell's shapes (48 slots, context 2048, page 16,
+    6,144 pages): the server's donated decode step compiles, its latent
+    pool of all 27 layers is aliased in place, and the 9.8 GB of weights,
+    the 3.1 GB pool and the step's temporaries fit one chip with room
+    for the prefill step's (1.2 GB when this test was written)."""
+
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.runtime.kv import PagedKVSpec
+    from repro.runtime.serve import server_steps
+
+    cfg = get_config("deepseek-v2-lite")
+    api = build_model(cfg.replace(moe=dataclasses.replace(cfg.moe, held=16)))
+    batch, context = 48, 2048
+    spec = PagedKVSpec.for_server(context=context, page_size=16,
+                                  n_pages=6144, batch=batch)
+    params = _on(one_chip, jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: api.init_decode_state(batch, context, spec,
+                                      dtype=jnp.bfloat16)))
+    (pool,) = jax.tree.leaves(state["blocks"]["0_moe"]["kv"])
+    assert pool.shape[0] == 27
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    active = jax.ShapeDtypeStruct((batch,), jnp.bool_, sharding=one_chip)
+    decode, _, _ = server_steps(api, paged=True)
+    mem = decode.lower(params, state, i32(batch, 1), i32(batch), active,
+                       i32(batch, spec.pages_per_slot)).compile(
+                       ).memory_analysis()
+    assert mem.alias_size_in_bytes >= pool.size * pool.dtype.itemsize
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 14 * 10**9)
