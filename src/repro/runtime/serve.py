@@ -38,6 +38,10 @@ re-prefilled through the chunked path on resume.  Chunked prefill is
 tokenwise-exact, so a preempted request's final output is byte-identical
 to an undisturbed run.  The page size is a tunable
 (:class:`~repro.runtime.tunables.KVPageTunable`, ``serve.kv_page``).
+The pool is indexed by page, not slot, so where it is the whole decode
+state the prefill step runs over the prefilling slots' rows alone,
+padded to the next of :func:`prefill_rungs` (every rung compiled at the
+first prefill), not over every slot.
 
 ``share_prefix=True`` (paged only) adds **copy-on-write prefix
 sharing**: at placement the server looks for a live slot whose written
@@ -115,6 +119,24 @@ def _snapshot(a: np.ndarray) -> jax.Array:
     (``tokens``, ``lengths``, ``commit``, ``mask``) are never written
     after dispatch and may alias freely."""
     return jnp.asarray(np.array(a))
+
+
+def prefill_rungs(batch: int) -> tuple[int, ...]:
+    """The row counts a compacted paged prefill step is dispatched at:
+    the powers of four below ``batch``, then ``batch`` — at most
+    ⌈log₄ batch⌉ + 1 compiled programs."""
+
+    rungs, r = [], 1
+    while r < batch:
+        rungs.append(r)
+        r *= 4
+    return (*rungs, batch)
+
+
+def prefill_rung(n: int, batch: int) -> int:
+    """The smallest rung of :func:`prefill_rungs` that holds ``n`` rows."""
+
+    return next(r for r in prefill_rungs(batch) if r >= n)
 
 
 def server_steps(api: ModelAPI, paged: bool) -> tuple:
@@ -296,6 +318,14 @@ class Server:
                 "share_prefix=True needs pure-attention decode state: "
                 "SSM/recurrent state at the share point is per-slot and "
                 "has no position index to share through")
+        # the paged pool is indexed by page, not slot, so where it is
+        # the whole state a prefill step need only carry the prefilling
+        # slots' rows (padded to a rung); a per-slot leaf (SSM
+        # recurrence, cross K/V) keeps the full-batch step
+        self._compact_prefill = paged and all(
+            any(getattr(k, "key", None) == "kv" for k in path)
+            for path, _ in jax.tree_util.tree_leaves_with_path(self.state))
+        self._rungs_warm = False
         self.slot_req: list[Request | None] = [None] * batch
         self.slot_pos = np.zeros(batch, np.int32)   # per-slot token count
         self._slot_dirty = np.zeros(batch, bool)    # retired -> stale state
@@ -310,6 +340,8 @@ class Server:
         self.slot_ticks = 0         # sum of active slots over ticks
         self.tokens_generated = 0
         self.prefill_chunks = 0
+        self.prefill_rows_valid = 0       # chunk tokens prefilled
+        self.prefill_rows_dispatched = 0  # rows × chunk the steps computed
         self.spec_ticks = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -669,6 +701,11 @@ class Server:
             "mean_active": (self.slot_ticks / self.ticks
                             if self.ticks else 0.0),
             "prefill_chunks": float(self.prefill_chunks),
+            "prefill_rows_valid": float(self.prefill_rows_valid),
+            "prefill_rows_dispatched": float(self.prefill_rows_dispatched),
+            "prefill_pad_share": (
+                1.0 - self.prefill_rows_valid / self.prefill_rows_dispatched
+                if self.prefill_rows_dispatched else 0.0),
             "deferrals": float(self.deferrals),
             "preemptions": float(self.preemptions),
             "peak_active": float(self.peak_active),
@@ -730,7 +767,9 @@ class Server:
         speculative commit) or ``prefill`` — ``serve.pack.<step>`` (host
         inputs and their uploads), ``serve.dispatch.<step>`` (the jitted
         call), ``serve.readback.<step>`` (argmax to the host) and
-        ``serve.commit.<step>`` (per-slot bookkeeping)."""
+        ``serve.commit.<step>`` (per-slot bookkeeping).  The prefill's
+        pack and dispatch carry ``slots`` and the ``rung`` of rows the
+        step ran."""
 
         with span("serve.tick", self.obs, self.ticks):
             return self._tick()
@@ -923,12 +962,35 @@ class Server:
                 self._retire_if_done(s)
         return table
 
+    def _warm_prefill_rungs(self) -> None:
+        """Run every rung of the compacted prefill step once, every
+        length 0 (no row is written), and the readback's argmax over its
+        logits, so that neither compiles in a later tick."""
+
+        T, M = self.prefill_chunk, self.alloc.spec.pages_per_slot
+        for K in prefill_rungs(self.batch):
+            zeros = jnp.zeros(K, jnp.int32)
+            logits, self.state = self._prefill_step(
+                self.params, self.state, jnp.zeros((K, T), jnp.int32),
+                zeros, zeros, jnp.full((K, M), NO_PAGE, jnp.int32))
+            jnp.argmax(logits, axis=-1)
+        self._rungs_warm = True
+
     def _run_prefill(self, prefill: list[int], table) -> None:
+        # compacted: row i of the step is slot prefill[i], padded with
+        # length-0 rows (position 0, no pages) to the next rung; else
+        # row s is slot s over the whole batch
         ob, t = self.obs, self.ticks
-        with span("serve.pack.prefill", ob, t):
-            T = self.prefill_chunk
-            tokens = np.zeros((self.batch, T), np.int32)
-            lengths = np.zeros(self.batch, np.int32)
+        T = self.prefill_chunk
+        if self._compact_prefill:
+            K = prefill_rung(len(prefill), self.batch)
+            row = {s: i for i, s in enumerate(prefill)}
+        else:
+            K = self.batch
+            row = {s: s for s in prefill}
+        with span("serve.pack.prefill", ob, t, slots=len(prefill), rung=K):
+            tokens = np.zeros((K, T), np.int32)
+            lengths = np.zeros(K, np.int32)
             for s in prefill:
                 req = self.slot_req[s]
                 cur = req._cursor  # type: ignore[attr-defined]
@@ -936,26 +998,43 @@ class Server:
                 # request re-prefills prompt + out and resumes exactly
                 stream = req.prompt + req.out
                 n = min(T, req._prefill_target - cur)
-                tokens[s, :n] = stream[cur:cur + n]
-                lengths[s] = n
-            table = self._table(table)
-            extra = (table,) if self.paged else ()
-            tok, pos = jnp.asarray(tokens), _snapshot(self.slot_pos)
-            lens = jnp.asarray(lengths)
-        with span("serve.dispatch.prefill", ob, t):
+                tokens[row[s], :n] = stream[cur:cur + n]
+                lengths[row[s]] = n
+            if self._compact_prefill:
+                # the prefilling slots' rows of the host table equal the
+                # tick's snapshot: a step before this one only releases
+                # or rewinds its own (decoding) slots
+                idx = np.asarray(prefill)
+                positions = np.zeros(K, np.int32)
+                positions[:len(prefill)] = self.slot_pos[idx]
+                pages = np.full((K, self.alloc.spec.pages_per_slot), NO_PAGE,
+                                np.int32)
+                pages[:len(prefill)] = self.alloc.page_table[idx]
+                pos, extra = jnp.asarray(positions), (jnp.asarray(pages),)
+            else:
+                table = self._table(table)
+                extra = (table,) if self.paged else ()
+                pos = _snapshot(self.slot_pos)
+            tok, lens = jnp.asarray(tokens), jnp.asarray(lengths)
+        with span("serve.dispatch.prefill", ob, t, slots=len(prefill),
+                  rung=K):
+            if self._compact_prefill and not self._rungs_warm:
+                self._warm_prefill_rungs()
             logits, self.state = self._prefill_step(
                 self.params, self.state, tok, pos, lens, *extra)
         with span("serve.readback.prefill", ob, t):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
         with span("serve.commit.prefill", ob, t, slots=len(prefill)):
             self.prefill_chunks += len(prefill)
+            self.prefill_rows_valid += int(lengths.sum())
+            self.prefill_rows_dispatched += K * T
             for s in prefill:
                 req = self.slot_req[s]
-                n = int(lengths[s])
+                n = int(lengths[row[s]])
                 req._cursor += n  # type: ignore[attr-defined]
                 self.slot_pos[s] += n
                 if req._cursor >= req._prefill_target:
-                    req.out.append(int(nxt[s]))
+                    req.out.append(int(nxt[row[s]]))
                     self.tokens_generated += 1
                     self._retire_if_done(s)
 
